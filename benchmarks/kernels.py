@@ -1,7 +1,8 @@
 """MMA kernel benchmarks: (a) the merged-vs-cascaded structural claim on the
 lowered HLO (HBM-materialized intermediates — the TPU analogue of the
-initial-delay accounting), (b) CPU wall-time of each datapath at a
-representative layer shape, (c) early-termination scaling with planes.
+initial-delay accounting), (b) wall time of each datapath at a
+representative layer shape on the platform JAX runs on (named in every row),
+(c) early-termination scaling with planes.
 """
 from __future__ import annotations
 
@@ -28,7 +29,8 @@ def _count_hbm_intermediates(fn, *args) -> dict:
             if d:
                 n *= int(d)
         nbytes += n * {"f32": 4, "s32": 4, "bf16": 2, "s8": 1}.get(dtype, 4)
-    return {"dot_count": len(dots), "dot_out_bytes": nbytes}
+    return {"dot_count": len(dots), "dot_out_bytes": nbytes,
+            "custom_calls": text.count("tpu_custom_call")}
 
 
 def _time(fn, *args, repeats=5) -> float:
@@ -55,19 +57,21 @@ def run() -> list[tuple[str, float, str]]:
     # Pallas kernel (one fused call, Horner residual in VMEM — ONE output
     # tensor ever touches HBM); the cascade materializes one full (M,N)
     # partial product per plane.  We count materialized dot outputs in the
-    # optimized HLO of each.
+    # optimized HLO of each.  ``ops`` runs the kernel in interpret mode off
+    # the TPU, where its body is inlined and its 8 per-plane dots appear as
+    # XLA dots; on the TPU the pallas_call is ONE custom call and only
+    # out_specs' (M,N) int32 tile ever reaches HBM (by construction).
     from repro.kernels import ops
 
-    sm = _count_hbm_intermediates(
-        lambda a, b: ops.mma_matmul(a, b, interpret=True), x, w)
+    platform = jax.devices()[0].platform
+    pallas = jax.jit(lambda a, b: ops.mma_matmul(a, b))
+    sm = _count_hbm_intermediates(pallas, x, w)
     sc = _count_hbm_intermediates(
         lambda a, b: bitplane.bitplane_matmul_cascade(a, b), x, w)
     m_out_bytes = x.shape[0] * w.shape[1] * 4  # the single fused output
-    # NOTE: interpret mode inlines the kernel body, so its 8 per-plane dots
-    # appear as XLA dots here; on TPU the pallas_call is ONE custom call and
-    # only out_specs' (M,N) int32 tile ever reaches HBM (by construction).
     rows.append(("kernels/merged_pallas_hlo", 0.0,
-                 f"inlined_interpret_dots={sm['dot_count']};"
+                 f"xla_dots={sm['dot_count']};"
+                 f"tpu_custom_calls={sm['custom_calls']};"
                  f"hbm_out_bytes={m_out_bytes} (single out_specs tile)"))
     rows.append(("kernels/cascade_hlo", _time(cascade, x, w) * 1e6,
                  f"dots={sc['dot_count']};dot_bytes={sc['dot_out_bytes']};"
@@ -76,9 +80,9 @@ def run() -> list[tuple[str, float, str]]:
                  "unrolled Horner (XLA fuses adds, still 8 plane dots)"))
     rows.append(("kernels/int8_direct", _time(int8, x, w) * 1e6, "bit-parallel baseline"))
 
-    t = _time(lambda a, b: ops.mma_matmul(a, b, interpret=True), x[:32], w[:, :128],
-              repeats=2)
-    rows.append(("kernels/pallas_interpret", t * 1e6, "interpret-mode (CPU)"))
+    t = _time(pallas, x[:32], w[:, :128], repeats=2)
+    rows.append(("kernels/pallas", t * 1e6,
+                 "interpret mode" if platform != "tpu" else "Mosaic kernel"))
 
     # early termination: flops scale ~ planes/8
     for planes in (8, 6, 4, 2):
@@ -89,4 +93,6 @@ def run() -> list[tuple[str, float, str]]:
         )
         rows.append((f"kernels/planes_{planes}", _time(fn, x, w) * 1e6,
                      f"hlo_flops={flops:.3e}"))
-    return rows
+    # every time above is wall time on this platform, not the chip's
+    return [(name, us, f"{derived};platform={platform}")
+            for name, us, derived in rows]

@@ -27,6 +27,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def msdf_rows():
     """Cycle-count claims from the MSDF simulator (paper Sec. 3.2)."""
@@ -58,6 +60,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--section", default="all")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rows: list[tuple[str, float, str]] = []
     if args.section in ("all", "msdf"):

@@ -17,11 +17,13 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.configs.base import QuantConfig
 from repro.core.plane_schedule import PlaneSchedule
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.serve.engine import lm_schedule_from_params
 
 
 def main():
+    enable_compile_cache()
     cfg = get_smoke_config("yi_6b")
     mod = build(cfg)
     params = mod.init_params(jax.random.PRNGKey(0), cfg)

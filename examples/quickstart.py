@@ -12,16 +12,18 @@ import numpy as np
 
 from repro.core import bitplane, early_term, mma, quant
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.integers(-128, 128, (64, 256)), jnp.int8)
     w = jnp.asarray(rng.integers(-128, 128, (256, 32)), jnp.int8)
 
     exact = ref.mma_matmul_ref(x, w)
     merged = mma.mma_dot(x, w, impl="xla")
-    pallas = ops.mma_matmul(x, w, interpret=True)
+    pallas = ops.mma_matmul(x, w)  # interpret mode only off the TPU
     print("merged == exact:", bool(jnp.array_equal(merged, exact)))
     print("pallas == exact:", bool(jnp.array_equal(pallas, exact)))
 

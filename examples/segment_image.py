@@ -17,6 +17,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import unet
 from repro.segserve import SegEngine, halo_for
 from repro.segserve.synth import phantom_image
@@ -36,6 +37,7 @@ def main():
                     help="float datapath (bit-comparable to whole-image "
                          "forward; no precision/energy story)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = unet.UNetConfig(
         hw=args.height, in_ch=4, base=args.base, depth=args.depth,
@@ -58,9 +60,9 @@ def main():
           f"classes present {sorted(np.unique(mask).tolist())}")
     print(f"tiles={res.n_tiles} (halo {halo_for(args.depth, 1)} px), "
           f"budget classes {res.class_counts}")
-    print(f"modeled: {res.cycles} cycles = {res.time_ms:.2f} ms @100MHz, "
-          f"{res.gops:.2f} GOPS, {res.gops_per_w:.2f} GOPS/W, "
-          f"{res.energy_mj:.1f} mJ")
+    print(f"modeled FPGA, not device time: {res.cycles} cycles = "
+          f"{res.time_ms:.2f} ms @100MHz, {res.gops:.2f} GOPS, "
+          f"{res.gops_per_w:.2f} GOPS/W, {res.energy_mj:.1f} mJ")
 
 
 if __name__ == "__main__":

@@ -14,6 +14,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.configs.base import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.serve.engine import Engine, Request, lm_schedule_from_params
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--target-rel-err", type=float, default=None,
                     help="build a per-layer PlaneSchedule from the weights")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.target_rel_err is not None and not args.quant:
         ap.error("--target-rel-err requires --quant (schedules drive the "
                  "mma_int8 datapath)")

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import unet
 from repro.optim import adamw
 from repro.checkpoint.ckpt import Checkpointer
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--ckpt", default="/tmp/unet_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = unet.UNetConfig() if args.full else unet.UNetConfig(
         hw=32, in_ch=2, base=8, depth=2, n_classes=3

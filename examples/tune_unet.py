@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 from repro import autotune
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import unet
 from repro.segserve import SegEngine
 from repro.segserve.synth import phantom_image
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--plan-path", default=None,
                     help="write the certified plan JSON here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = unet.UNetConfig(
         hw=args.height, in_ch=4, base=args.base, depth=args.depth,

@@ -92,14 +92,9 @@ def _mma_kernel(x_ref, w_ref, *refs, planes: int, signed: bool, n_k: int,
 
 
 def _compiler_params():
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except AttributeError:  # older pallas API
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary")
+    )
 
 
 def _mma_matmul_impl(

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.configs.base import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.serve.engine import Engine, Request
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--quant", default="none")
     ap.add_argument("--planes", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     if args.quant != "none":

@@ -17,6 +17,7 @@ import jax
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import QuantConfig
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build
 from repro.optim import adamw
 from repro.train import train_step as ts
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--quant", default="none")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.quant != "none":

@@ -127,7 +127,6 @@ def moe_ffn_ep(p: dict, x: jax.Array, cfg) -> jax.Array:
     indices force token replication (olmoe train_4k baseline: 243 s
     collective term vs 0.4 s compute — EXPERIMENTS.md §Perf iteration 1).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel.sharding import current_mesh, spec_for
@@ -186,12 +185,12 @@ def moe_ffn_ep(p: dict, x: jax.Array, cfg) -> jax.Array:
         y = _local_combine(oe_local, meta, bl * sl, cap, xs.dtype)
         return y.reshape(bl, sl, d)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, p["router"]["w"], p["w_gate"], p["w_up"], p["w_down"])
 

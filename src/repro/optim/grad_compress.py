@@ -76,13 +76,11 @@ def compressed_psum_shardmap(mesh, axis_names=("data",)):
         synced = jax.tree.map(sync, q, s)
         return synced, new_err
 
-    from jax.experimental.shard_map import shard_map
-
     spec = P(*axis_names)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec),
         out_specs=(spec, spec),
-        check_rep=False,
+        check_vma=False,
     )

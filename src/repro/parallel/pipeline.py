@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import layers as L
@@ -118,13 +117,13 @@ def pipelined_loss_fn(params, batch, cfg, *, n_micro: int, axis: str = "model"):
         return loss
 
     blocks_spec = jax.tree.map(lambda _: P(axis), params["blocks"])
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(blocks_spec, P(), P(), P(),
                   P(None, dp, None), P(None, dp, None)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     loss = fn(params["blocks"], params["embed"], params["ln_f"],
               params["head"], tok_mb, tgt_mb)

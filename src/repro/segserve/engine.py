@@ -19,7 +19,8 @@ point of the slot table.
 Accounting mirrors the LM engine's energy story, per *image*: relation-(2)
 cycles of every tile the image consumed (halo overhead included, priced
 honestly) under its refined schedule, against the useful whole-canvas ops
-— time, GOPS and GOPS/W at the paper's implied accelerator power.
+— time, GOPS and GOPS/W at the paper's implied accelerator power.  These
+are FPGA-model figures, not measurements of the device running the engine.
 """
 from __future__ import annotations
 
@@ -70,7 +71,14 @@ def _shared_forward(per_tile_quant: bool):
 
 @dataclass
 class SegResult:
-    """One served image: stitched logits + the modeled energy account."""
+    """One served image: stitched logits + the modeled energy account.
+
+    Every figure besides ``logits`` is modeled for the paper's FPGA, not
+    measured: ``cycles`` are relation-(2) cycles, ``time_ms`` is those
+    cycles at the FPGA's 100 MHz clock, ``gops_per_w`` and ``energy_mj``
+    apply the paper's implied accelerator power, and ``pj`` is the FPGA pJ
+    model.  None of them is time or energy on the device that ran the
+    forward."""
 
     logits: np.ndarray  # (H, W, n_classes) f32
     cycles: int

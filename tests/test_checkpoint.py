@@ -69,7 +69,9 @@ def test_elastic_restore_reshards(tmp_path):
     ck = Checkpointer(tmp_path)
     st = _state(1)
     ck.save(1, st)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), jax.eval_shape(lambda: st))
     restored, _ = ck.restore(jax.eval_shape(lambda: st), shardings=sh)
     assert restored["params"]["w"].sharding == NamedSharding(mesh, P())

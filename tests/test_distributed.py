@@ -5,10 +5,9 @@ Covers: logical sharding rules + divisibility fallback, param-spec
 derivation, grad-compression collective (error feedback across steps), and
 a tiny end-to-end sharded train step on a 4x2 mesh.
 """
-import json
 import subprocess
 import sys
-import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -19,7 +18,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.parallel import sharding as shd
 from repro.parallel import param_specs as pspecs
 
@@ -52,7 +51,7 @@ assert emb.spec == P("model", None), emb.spec      # vocab-sharded
 
 # --- grad compression: compressed mean-allreduce with error feedback
 from repro.optim import grad_compress as gc
-mesh1 = jax.make_mesh((8,), ("data",))
+mesh1 = make_mesh((8,), ("data",))
 f = gc.compressed_psum_shardmap(mesh1, ("data",))
 rng = np.random.default_rng(0)
 g_local = jnp.asarray(rng.standard_normal((8, 128)), jnp.float32)  # per-shard
@@ -102,8 +101,8 @@ assert abs(loss - float(m1["loss"])) < 5e-2, (loss, float(m1["loss"]))
 import tempfile
 from repro.checkpoint.ckpt import Checkpointer
 from jax.sharding import NamedSharding
-meshA = jax.make_mesh((4, 2), ("data", "model"))
-meshB = jax.make_mesh((2, 4), ("data", "model"))
+meshA = make_mesh((4, 2), ("data", "model"))
+meshB = make_mesh((2, 4), ("data", "model"))
 with tempfile.TemporaryDirectory() as td:
     ck = Checkpointer(td)
     w = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
@@ -141,7 +140,7 @@ def test_distributed_subprocess():
         # jax probes for a TPU backend in the stripped env and can hang for
         # minutes before falling back.
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo",
+             "HOME": str(Path.home()), "JAX_PLATFORMS": "cpu"},
+        cwd=Path(__file__).resolve().parents[1],
     )
     assert "SUBPROCESS_OK" in r.stdout, r.stdout + "\n" + r.stderr

@@ -111,3 +111,32 @@ def test_dryrun_results_complete():
                 assert r["cost"]["flops"] > 0, p.name
                 assert r["roofline"]["dominant"] in ("compute", "memory", "collective")
     assert not missing, missing
+
+
+@pytest.fixture
+def cache_dir_config():
+    """Restore JAX's compile-cache directory after a test changes it."""
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "repo"])
+def test_compile_cache_placement(monkeypatch, tmp_path, cache_dir_config,
+                                 from_env):
+    """The variable names the cache and nothing is set in code; without it
+    the cache sits at the fixed ``<repo root>/.jax_cache``."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want

@@ -2,6 +2,7 @@
 loss; gradients must flow (subprocess with 8 forced host devices)."""
 import subprocess
 import sys
+from pathlib import Path
 
 SUB = """
 import os
@@ -9,6 +10,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config
 from repro.models import build
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as shd
 from repro.parallel.pipeline import pipelined_loss_fn, bubble_fraction
 
@@ -22,7 +24,7 @@ batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (8, 33)), jnp.int32)}
 ref_loss, _ = mod.loss_fn(params, batch, cfg)
 ref_loss = float(ref_loss)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))  # PP=2, DP=4
+mesh = make_mesh((4, 2), ("data", "model"))  # PP=2, DP=4
 with shd.use_mesh(mesh):
     loss_fn = lambda p, b: pipelined_loss_fn(p, b, cfg, n_micro=2)[0]
     pp_loss = float(jax.jit(loss_fn)(params, batch))
@@ -44,8 +46,10 @@ print("PIPELINE_OK", pp_loss, ref_loss)
 def test_pipeline_subprocess():
     r = subprocess.run(
         [sys.executable, "-c", SUB], capture_output=True, text=True, timeout=900,
+        # JAX_PLATFORMS=cpu: a forced-host-device test; without it jax probes
+        # for a TPU backend in the stripped env before falling back.
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin",
-             "HOME": "/root"},
-        cwd="/root/repo",
+             "HOME": str(Path.home()), "JAX_PLATFORMS": "cpu"},
+        cwd=Path(__file__).resolve().parents[1],
     )
     assert "PIPELINE_OK" in r.stdout, r.stdout + "\n" + r.stderr[-3000:]

@@ -1,6 +1,7 @@
 """End-to-end system behaviour: serving engine, quantized-serving params,
 int8 KV cache, sharding-rule fallbacks, U-Net paper pipeline."""
 import dataclasses
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -153,9 +154,10 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel import sharding as shd
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 with shd.use_mesh(mesh, shd.EP_DP_RULES):
     # batch 8 divides pod*data*model=8 -> all three
     assert shd.spec_for(("batch",), (8,)) == P(("pod", "data", "model"))
@@ -168,8 +170,9 @@ with shd.use_mesh(mesh, shd.EP_DP_RULES):
 print("OK")
 """
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=300, cwd="/root/repo",
-                       env={"PYTHONPATH": "src", "HOME": "/root",
+                       text=True, timeout=300,
+                       cwd=Path(__file__).resolve().parents[1],
+                       env={"PYTHONPATH": "src", "HOME": str(Path.home()),
                             "PATH": "/usr/bin:/bin:/usr/local/bin",
                             # forced-host-device test: skip TPU probing,
                             # which can hang for minutes in a stripped env
