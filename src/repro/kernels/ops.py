@@ -51,21 +51,23 @@ def mma_matmul(
     m = 1
     for d in lead:
         m *= d
-    x2 = x.reshape(m, k)
 
     bm, bk, bn = block if block is not None else (BM, BK, BN)
     # Shrink blocks for small problems (keeps interpret-mode tests fast);
     # int8 sublane tiling on TPU wants the second-minor dim in multiples of 32.
     bm, bk, bn = min(bm, _pad_to(m, 32)), min(bk, _pad_to(k, 128)), min(bn, _pad_to(n, 128))
     mp, kp, np_ = _pad_to(m, bm), _pad_to(k, bk), _pad_to(n, bn)
-    # Zero-padding K is exact: padded w rows are 0, so both the dot and the
-    # signed colsum correction are unaffected (see kernel docstring).
-    x2 = jnp.pad(x2, ((0, mp - m), (0, kp - k)))
-    w2 = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
-    out = mma_matmul_pallas(
-        x2, w2, planes=planes, signed=signed, interpret=interpret, bm=bm, bk=bk, bn=bn
-    )
-    return out[:m, :n].reshape(*lead, n)
+    with jax.named_scope("pack"):
+        # Zero-padding K is exact: padded w rows are 0, so both the dot and
+        # the signed colsum correction are unaffected (see kernel docstring).
+        x2 = jnp.pad(x.reshape(m, k), ((0, mp - m), (0, kp - k)))
+        w2 = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
+    with jax.named_scope("mma"):
+        out = mma_matmul_pallas(
+            x2, w2, planes=planes, signed=signed, interpret=interpret, bm=bm, bk=bk, bn=bn
+        )
+    with jax.named_scope("pack"):
+        return out[:m, :n].reshape(*lead, n)
 
 
 def mma_matmul_scaled(
@@ -90,17 +92,19 @@ def mma_matmul_scaled(
     m = 1
     for d in lead:
         m *= d
-    x2 = x.reshape(m, k)
     bm, bk, bn = min(BM, _pad_to(m, 32)), min(BK, _pad_to(k, 128)), min(BN, _pad_to(n, 128))
     mp, kp, np_ = _pad_to(m, bm), _pad_to(k, bk), _pad_to(n, bn)
-    x2 = jnp.pad(x2, ((0, mp - m), (0, kp - k)))
-    w2 = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
-    ws = jnp.pad(w_scale.reshape(-1), (0, np_ - n))
-    out = mma_matmul_scaled_pallas(
-        x2, w2, x_scale, ws, planes=planes, signed=signed, interpret=interpret,
-        bm=bm, bk=bk, bn=bn,
-    )
-    return out[:m, :n].reshape(*lead, n)
+    with jax.named_scope("pack"):
+        x2 = jnp.pad(x.reshape(m, k), ((0, mp - m), (0, kp - k)))
+        w2 = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
+        ws = jnp.pad(w_scale.reshape(-1), (0, np_ - n))
+    with jax.named_scope("mma"):
+        out = mma_matmul_scaled_pallas(
+            x2, w2, x_scale, ws, planes=planes, signed=signed, interpret=interpret,
+            bm=bm, bk=bk, bn=bn,
+        )
+    with jax.named_scope("pack"):
+        return out[:m, :n].reshape(*lead, n)
 
 
 def mma_conv2d(
@@ -134,22 +138,23 @@ def mma_conv2d(
     kh, kw, cin, cout = w.shape
     assert c == cin
     pad_widths = ((0, 0), (pad, pad), (pad, pad), (0, 0))
-    if pad_mode == "zero":
-        xp = jnp.pad(x, pad_widths)
-    elif pad_mode in ("edge", "reflect"):
-        xp = jnp.pad(x, pad_widths, mode=pad_mode)
-    else:
-        raise ValueError(f"unknown pad_mode {pad_mode!r}")
     oh = (h + 2 * pad - kh) // stride + 1
     ow = (w_ + 2 * pad - kw) // stride + 1
-    patches = [
-        xp[:, i : i + oh * stride : stride, j : j + ow * stride : stride, :]
-        for i in range(kh)
-        for j in range(kw)
-    ]
-    patches = jnp.concatenate(patches, axis=-1)
-    wm = w.reshape(kh * kw * cin, cout)
-    pm = patches.reshape(-1, kh * kw * cin)
+    with jax.named_scope("im2col"):
+        if pad_mode == "zero":
+            xp = jnp.pad(x, pad_widths)
+        elif pad_mode in ("edge", "reflect"):
+            xp = jnp.pad(x, pad_widths, mode=pad_mode)
+        else:
+            raise ValueError(f"unknown pad_mode {pad_mode!r}")
+        patches = [
+            xp[:, i : i + oh * stride : stride, j : j + ow * stride : stride, :]
+            for i in range(kh)
+            for j in range(kw)
+        ]
+        patches = jnp.concatenate(patches, axis=-1)
+        wm = w.reshape(kh * kw * cin, cout)
+        pm = patches.reshape(-1, kh * kw * cin)
     if impl == "pallas":
         out = mma_matmul(
             pm, wm, planes=planes, signed=signed, interpret=interpret

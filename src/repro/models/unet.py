@@ -150,13 +150,16 @@ def conv3x3(p, x, cfg: UNetConfig, *, planes: int | None = None):
         from repro.core import quant
         from repro.kernels import ops
 
-        xq = quant.quantize_acts(x)
-        wq = quant.quantize_weights(p["w"], channel_axis=-1)
+        with jax.named_scope("quant"):
+            xq = quant.quantize_acts(x)
+            wq = quant.quantize_weights(p["w"], channel_axis=-1)
         out = ops.mma_conv2d(
             xq.values, wq.values, planes=planes, impl=cfg.impl,
             pad_mode=cfg.pad_mode,
         )
-        out = out.astype(jnp.float32) * quant.quantized_matmul_scale(xq.scale, wq.scale)
+        with jax.named_scope("rescale"):
+            out = out.astype(jnp.float32) * quant.quantized_matmul_scale(xq.scale, wq.scale)
+            return out + p["b"]
     elif cfg.pad_mode == "zero":
         out = jax.lax.conv_general_dilated(
             x, p["w"], (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
@@ -212,8 +215,9 @@ def forward(params, x, cfg: UNetConfig, *, planes_arr=None, taps=None):
             pl = planes_arr[li]
         else:
             pl = sched.planes_for(li) if sched is not None else None
+        with jax.named_scope(f"conv{li:02d}"):
+            out = jax.nn.relu(conv3x3(conv, h, cfg, planes=pl))
         li += 1
-        out = jax.nn.relu(conv3x3(conv, h, cfg, planes=pl))
         if taps is not None:
             taps.append(out)
         return out
@@ -224,25 +228,28 @@ def forward(params, x, cfg: UNetConfig, *, planes_arr=None, taps=None):
         for conv in stage:
             h = qconv(conv, h)
         skips.append(h)
-        h = jax.lax.reduce_window(
-            h, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID"
-        )
+        with jax.named_scope("pool"):
+            h = jax.lax.reduce_window(
+                h, -jnp.inf, jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1), "VALID"
+            )
     for conv in params["bottleneck"]:
         h = qconv(conv, h)
     for d, stage in enumerate(params["dec"]):
         # 2x nearest upsample (off-accelerator op, like the paper's 2x2 path)
-        n, hh, ww, c = h.shape
-        h = jnp.broadcast_to(h[:, :, None, :, None, :], (n, hh, 2, ww, 2, c)).reshape(
-            n, hh * 2, ww * 2, c
-        )
-        h = jnp.concatenate([skips[-(d + 1)], h], axis=-1)
+        with jax.named_scope("upsample"):
+            n, hh, ww, c = h.shape
+            h = jnp.broadcast_to(h[:, :, None, :, None, :], (n, hh, 2, ww, 2, c)).reshape(
+                n, hh * 2, ww * 2, c
+            )
+            h = jnp.concatenate([skips[-(d + 1)], h], axis=-1)
         for conv in stage:
             h = qconv(conv, h)
-    out = jax.lax.conv_general_dilated(
-        h, params["head"]["w"], (1, 1), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    return out + params["head"]["b"]
+    with jax.named_scope("head"):
+        out = jax.lax.conv_general_dilated(
+            h, params["head"]["w"], (1, 1), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
+        return out + params["head"]["b"]
 
 
 def forward_with_error_bound(params, x, cfg: UNetConfig):

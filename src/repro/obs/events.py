@@ -51,7 +51,7 @@ export     donor side of a steal, per request                   gateway
 import     thief side of a steal, per request (re-keyed rid;    gateway
            original ``arrival`` travels with it — span
            assembly treats it as the request's queue-enter)
-lm-prefill / lm-step / seg-batch
+lm-prefill / lm-step
            engine-local micro-step records.  Engines do not     engines
            know the absolute modeled clock, so these are
            **sequence-stamped** (a per-engine monotonic

@@ -16,5 +16,11 @@
 """
 from . import adaptive, engine, synth, tiling  # noqa: F401
 from .adaptive import budget_class_from_thresholds  # noqa: F401
-from .engine import SegEngine, SegRequest, SegResult, TileEvent  # noqa: F401
+from .engine import (  # noqa: F401
+    SegCounters,
+    SegEngine,
+    SegRequest,
+    SegResult,
+    TileEvent,
+)
 from .tiling import halo_for, plan_tiles, stitch, tiled_forward  # noqa: F401

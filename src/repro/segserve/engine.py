@@ -37,7 +37,6 @@ from repro.core import cycle_model as cm
 from repro.core import energy_model as em
 from repro.core.plane_schedule import PlaneSchedule
 from repro.models import unet
-from repro.obs.events import NULL_SINK, Event
 from repro.serve.queue import FifoQueue, SlotTable
 
 from . import adaptive, tiling
@@ -45,6 +44,36 @@ from . import adaptive, tiling
 _IMPLIED_POWER_W = (
     cm.PAPER_TABLE1["proposed"]["gops"] / cm.PAPER_TABLE1["proposed"]["gops_w"]
 )
+
+
+SPAN_PREFIX = "segserve."
+
+
+def _span(name: str, **meta):
+    """A host span on the profiler's clock, the one the device's ops are
+    stamped on (``jax.profiler.TraceAnnotation``).  With no profiler
+    listening it costs one flag check; ``meta`` lands on the span as stats
+    (``rid=``, a step's window shape, class and tile count)."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **meta)
+
+
+@dataclass
+class SegCounters:
+    """Counts of the engine's work since it was built, always kept (one
+    integer add each).  ``tiles / tile_slots`` is the micro-batch fill,
+    ``window_pixels`` the input-window pixels run (halo included), and
+    ``host_syncs`` the blocking device-to-host reads, one per step."""
+
+    admitted: int = 0
+    completed: int = 0
+    steps: int = 0
+    tiles: int = 0
+    tile_slots: int = 0  # steps x batch
+    window_pixels: int = 0
+    host_syncs: int = 0
+    upload_bytes: int = 0
+    fetch_bytes: int = 0
+    executables: int = 0  # distinct (in_h, in_w, class) signatures run
 
 
 @functools.lru_cache(maxsize=2)
@@ -279,10 +308,8 @@ class SegEngine:
         self._cfg_for_class: dict[int, unet.UNetConfig] = {}
         self._pj_cache: dict[tuple[int, int, int], int] = {}
         self._next_rid = 0
-        # telemetry (repro.obs.events): engine-local micro-batch records,
-        # sequence-stamped — the gateway owns the cycle-exact account
-        self.obs = NULL_SINK
-        self._obs_seq = 0
+        self.counters = SegCounters()
+        self._signatures: set[tuple[int, int, int]] = set()
 
     # ----------------------------------------------------------- schedules
 
@@ -347,51 +374,61 @@ class SegEngine:
         return req
 
     def _admit(self, req: SegRequest) -> bool:
-        # Plan before occupying: a planning error must not leak the slot.
-        req.plan = tiling.plan_tiles(
-            req.image.shape[0], req.image.shape[1], depth=self.cfg.depth,
-            convs_per_stage=self.cfg.convs_per_stage, tile=self.tile,
-            halo=self.halo,
-        )
-        slot = self.slots.occupy(req)
-        if slot is None:
-            return False
-        req.slot = slot
-        canvas = tiling.pad_canvas(req.image.astype(np.float32), req.plan)
-        req.canvas_in = canvas
-        req.canvas_out = np.zeros(
-            (req.plan.pad_h, req.plan.pad_w, self.cfg.n_classes), np.float32
-        )
-        req.remaining = req.plan.n_tiles
-        req.ops = cm.model_ops(
-            cm.unet_conv_layers(
-                (req.plan.pad_h, req.plan.pad_w), self.cfg.in_ch,
-                self.cfg.base, self.cfg.depth, self.cfg.convs_per_stage,
+        with _span("admit", rid=req.rid):
+            # Plan before occupying: a planning error must not leak the slot.
+            with _span("plan", rid=req.rid):
+                req.plan = tiling.plan_tiles(
+                    req.image.shape[0], req.image.shape[1],
+                    depth=self.cfg.depth,
+                    convs_per_stage=self.cfg.convs_per_stage, tile=self.tile,
+                    halo=self.halo,
+                )
+            slot = self.slots.occupy(req)
+            if slot is None:
+                return False
+            req.slot = slot
+            with _span("canvas", rid=req.rid):
+                canvas = tiling.pad_canvas(
+                    req.image.astype(np.float32), req.plan)
+                req.canvas_in = canvas
+                req.canvas_out = np.zeros(
+                    (req.plan.pad_h, req.plan.pad_w, self.cfg.n_classes),
+                    np.float32,
+                )
+            req.remaining = req.plan.n_tiles
+            req.ops = cm.model_ops(
+                cm.unet_conv_layers(
+                    (req.plan.pad_h, req.plan.pad_w), self.cfg.in_ch,
+                    self.cfg.base, self.cfg.depth, self.cfg.convs_per_stage,
+                )
             )
-        )
-        amax = float(np.max(np.abs(canvas)))
-        if self.adaptive:
-            classes = adaptive.classify_tiles(
-                canvas, req.plan, max_class=self.max_class, amax=amax,
-                thresholds=(
-                    None if self.plan is None else self.plan.class_thresholds
-                ),
-            )
-        else:
-            classes = [0] * req.plan.n_tiles
-        # The octave key component keeps batch-shared dynamic scales
-        # compatible; under a plan the forward quantizes per tile, numerics
-        # are batch-composition independent, and splitting groups by octave
-        # would only fragment the packing — so collapse it.
-        if self.plan is not None:
-            octave = 0
-        else:
-            octave = int(math.floor(math.log2(amax))) if amax > 0 else 0
-        for ti, (spec, k) in enumerate(zip(req.plan.tiles, classes)):
-            key = (spec.in_h, spec.in_w, k, octave, req.group)
-            self._tasks.setdefault(key, []).append((req, ti))
-            req.class_counts[k] = req.class_counts.get(k, 0) + 1
-        return True
+            with _span("classify", rid=req.rid):
+                amax = float(np.max(np.abs(canvas)))
+                if self.adaptive:
+                    classes = adaptive.classify_tiles(
+                        canvas, req.plan, max_class=self.max_class, amax=amax,
+                        thresholds=(
+                            None if self.plan is None
+                            else self.plan.class_thresholds
+                        ),
+                    )
+                else:
+                    classes = [0] * req.plan.n_tiles
+            # The octave key component keeps batch-shared dynamic scales
+            # compatible; under a plan the forward quantizes per tile,
+            # numerics are batch-composition independent, and splitting
+            # groups by octave would only fragment the packing — so
+            # collapse it.
+            if self.plan is not None:
+                octave = 0
+            else:
+                octave = int(math.floor(math.log2(amax))) if amax > 0 else 0
+            for ti, (spec, k) in enumerate(zip(req.plan.tiles, classes)):
+                key = (spec.in_h, spec.in_w, k, octave, req.group)
+                self._tasks.setdefault(key, []).append((req, ti))
+                req.class_counts[k] = req.class_counts.get(k, 0) + 1
+            self.counters.admitted += 1
+            return True
 
     # ------------------------------------------------------------- stepping
 
@@ -452,47 +489,78 @@ class SegEngine:
         key = self._next_key(group)
         if key is None:
             return []
-        task_group = self._tasks[key]
-        taken, self._tasks[key] = task_group[: self.batch], task_group[self.batch :]
-        if not self._tasks[key]:
-            del self._tasks[key]
         in_h, in_w, k = key[0], key[1], key[2]
-        x = np.zeros((self.batch, in_h, in_w, self.cfg.in_ch), np.float32)
-        for b, (req, ti) in enumerate(taken):
-            spec = req.plan.tiles[ti]
-            x[b] = req.canvas_in[spec.y0 : spec.y1, spec.x0 : spec.x1]
-        out = np.asarray(self._fwd(self.params, jnp.asarray(x), self.class_cfg(k)))
-        events: list[TileEvent] = []
-        cyc = self._tile_cycles(in_h, in_w, k)  # one price, both accounts
-        pj = self._tile_pj(in_h, in_w, k)
-        for b, (req, ti) in enumerate(taken):
-            spec = req.plan.tiles[ti]
-            cy, cx = spec.crop
-            req.canvas_out[
-                spec.core_y0 : spec.core_y1, spec.core_x0 : spec.core_x1
-            ] = out[b][cy, cx]
-            req.cycles += cyc
-            req.pj += pj
-            req.remaining -= 1
-            req.emitted.append(ti)
-            if req.remaining == 0:
-                self._finish(req)
-            events.append(
-                TileEvent(
-                    rid=req.rid, tile=ti, klass=k, cycles=cyc,
-                    core=(
-                        spec.core_y0, spec.core_x0, spec.core_y1, spec.core_x1
-                    ),
-                    done=req.done, request=req, pj=pj,
-                )
-            )
-        if self.obs.enabled:
-            self._obs_seq += 1
-            self.obs.emit(Event(self._obs_seq, "seg-batch", dict(
-                klass=int(k), tiles=len(taken), cycles=int(cyc * len(taken)),
-                pj=int(pj * len(taken)),
-            )))
+        task_group = self._tasks[key]
+        taken = task_group[: self.batch]
+        with _span("step", in_h=in_h, in_w=in_w, klass=k, tiles=len(taken)):
+            self._tasks[key] = task_group[self.batch :]
+            if not self._tasks[key]:
+                del self._tasks[key]
+            with _span("gather"):
+                x = np.zeros(
+                    (self.batch, in_h, in_w, self.cfg.in_ch), np.float32)
+                for b, (req, ti) in enumerate(taken):
+                    spec = req.plan.tiles[ti]
+                    x[b] = req.canvas_in[spec.y0 : spec.y1, spec.x0 : spec.x1]
+            with _span("upload"):
+                x_dev = jnp.asarray(x)
+            with _span("dispatch"):
+                out = self._fwd(self.params, x_dev, self.class_cfg(k))
+            with _span("fetch"):
+                out = np.asarray(out)
+            c = self.counters
+            c.steps += 1
+            c.tiles += len(taken)
+            c.tile_slots += self.batch
+            c.window_pixels += len(taken) * in_h * in_w
+            c.host_syncs += 1
+            c.upload_bytes += x.nbytes
+            c.fetch_bytes += out.nbytes
+            self._signatures.add((in_h, in_w, k))
+            c.executables = len(self._signatures)
+            with _span("stitch"):
+                events: list[TileEvent] = []
+                cyc = self._tile_cycles(in_h, in_w, k)  # one price, both accounts
+                pj = self._tile_pj(in_h, in_w, k)
+                for b, (req, ti) in enumerate(taken):
+                    spec = req.plan.tiles[ti]
+                    cy, cx = spec.crop
+                    req.canvas_out[
+                        spec.core_y0 : spec.core_y1, spec.core_x0 : spec.core_x1
+                    ] = out[b][cy, cx]
+                    req.cycles += cyc
+                    req.pj += pj
+                    req.remaining -= 1
+                    req.emitted.append(ti)
+                    if req.remaining == 0:
+                        self._finish(req)
+                    events.append(
+                        TileEvent(
+                            rid=req.rid, tile=ti, klass=k, cycles=cyc,
+                            core=(
+                                spec.core_y0, spec.core_x0,
+                                spec.core_y1, spec.core_x1,
+                            ),
+                            done=req.done, request=req, pj=pj,
+                        )
+                    )
         return events
+
+    def compiled_texts(self) -> dict[tuple[int, int, int], str]:
+        """HLO text of the compiled tile forward of each (in_h, in_w, class)
+        signature run so far.  Its ops' ``op_name`` metadata holds the
+        forward's named scopes, which a TPU trace's op events lack.  Each
+        is lowered and compiled again (JAX's compilation cache finds it
+        where the cache is on)."""
+        return {
+            (h, w, k): self._fwd.lower(
+                self.params,
+                jax.ShapeDtypeStruct((self.batch, h, w, self.cfg.in_ch),
+                                     jnp.float32),
+                self.class_cfg(k),
+            ).compile().as_text()
+            for h, w, k in sorted(self._signatures)
+        }
 
     def _finish(self, req: SegRequest) -> None:
         req.result = SegResult(
@@ -506,6 +574,7 @@ class SegEngine:
         self.slots.release(req.slot)
         req.canvas_in = None
         req.canvas_out = None
+        self.counters.completed += 1
 
     # ------------------------------------------------------------ the loop
 

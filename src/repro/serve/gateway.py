@@ -535,7 +535,6 @@ class SegAdapter:
             cfg = apply_plan(cfg, plan)
         self.cfg = cfg
         self.engine = SegEngine(cfg, self.params, plan=plan, **self._engine_kw)
-        self.engine.obs = self.obs_sink or NULL_SINK
         self._base_planes = tuple(self.engine._class_planes(0))
 
     # -- plan invalidation / hot reload

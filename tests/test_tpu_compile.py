@@ -80,7 +80,8 @@ def test_mma_kernel_compiles_at_unet_conv_shape(one_chip, layer, planes, scaled)
 def test_unet_tile_forward_compiles_with_one_kernel_per_conv(one_chip,
                                                              monkeypatch):
     """The served tile forward (``impl="pallas"``, a truncated per-layer
-    schedule) holds one Mosaic kernel per conv.  ``ops`` picks interpret
+    schedule) holds one Mosaic kernel per conv, and its ops' metadata keeps
+    the named scopes a trace reader finds them by.  ``ops`` picks interpret
     mode from the backend this process sees, the CPU: steer it here."""
     monkeypatch.setattr(ops, "_on_cpu", lambda: False)
     cfg = dataclasses.replace(CFG, plane_schedule=(8, 6, 5, 4, 5, 6, 8))
@@ -90,5 +91,8 @@ def test_unet_tile_forward_compiles_with_one_kernel_per_conv(one_chip,
     compiled = jax.jit(unet.forward, static_argnums=2).lower(
         params, x, cfg).compile()
     assert _kernel_calls(compiled) == len(LAYERS)
+    text = compiled.as_text()
+    for scope in ("conv00/im2col", "conv00/pack", "conv00/mma"):
+        assert f"jit(forward)/{scope}/" in text, scope
     # the tile forward fits one v5e chip's 16 GB with room to spare
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
