@@ -24,6 +24,7 @@ are FPGA-model figures, not measurements of the device running the engine.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import math
@@ -60,9 +61,14 @@ def _span(name: str, **meta):
 @dataclass
 class SegCounters:
     """Counts of the engine's work since it was built, always kept (one
-    integer add each).  ``tiles / tile_slots`` is the micro-batch fill,
-    ``window_pixels`` the input-window pixels run (halo included), and
-    ``host_syncs`` the blocking device-to-host reads, one per step."""
+    integer add each).  ``steps`` counts collected micro-batches; ``tiles
+    / tile_slots`` is the micro-batch fill, ``window_pixels`` the
+    input-window pixels run (halo included), and ``host_syncs`` the
+    blocking device-to-host reads, one per collected micro-batch.
+    ``launched_ahead`` counts the launches made while an earlier
+    micro-batch was still uncollected, so ``launched_ahead / steps`` is
+    the share of micro-batches the device started before the host had
+    fetched and stitched the one before."""
 
     admitted: int = 0
     completed: int = 0
@@ -71,6 +77,7 @@ class SegCounters:
     tile_slots: int = 0  # steps x batch
     window_pixels: int = 0
     host_syncs: int = 0
+    launched_ahead: int = 0
     upload_bytes: int = 0
     fetch_bytes: int = 0
     executables: int = 0  # distinct (in_h, in_w, class) signatures run
@@ -304,6 +311,9 @@ class SegEngine:
         self.slots: SlotTable[SegRequest] = SlotTable(max_active)
         # (in_h, in_w, class, amax_octave) -> [(request, tile_index), ...]
         self._tasks: dict[tuple[int, int, int, int], list] = {}
+        # micro-batches dispatched and not yet collected, oldest first:
+        # (key, [(request, tile_index), ...], device output)
+        self._inflight: collections.deque = collections.deque()
         self._fwd = _shared_forward(plan is not None and quantized)
         self._cfg_for_class: dict[int, unet.UNetConfig] = {}
         self._pj_cache: dict[tuple[int, int, int], int] = {}
@@ -433,19 +443,20 @@ class SegEngine:
     # ------------------------------------------------------------- stepping
 
     def has_work(self, group: str | None = ...) -> bool:
-        """Admitted tiles are waiting to run (the public surface callers —
-        the gateway's adapter — poll instead of reaching into the task
-        table).  Pass ``group`` to ask about one scheduling group only
-        (``...``, the default, means *any* group)."""
-        if group is ...:
-            return bool(self._tasks)
-        return any(key[4] == group for key in self._tasks)
+        """Admitted tiles are waiting to run or, dispatched, to be
+        collected (the public surface callers — the gateway's adapter —
+        poll instead of reaching into the task table).  Pass ``group`` to
+        ask about one scheduling group only (``...``, the default, means
+        *any* group)."""
+        return self.pending(group) > 0
 
     def pending(self, group: str | None = ...) -> int:
-        """How many admitted tiles are waiting to run."""
-        return sum(
-            len(g) for key, g in self._tasks.items()
-            if group is ... or key[4] == group
+        """How many admitted tiles are waiting to run or to be collected."""
+        def mine(key):
+            return group is ... or key[4] == group
+
+        return sum(len(g) for key, g in self._tasks.items() if mine(key)) + sum(
+            len(taken) for key, taken, _ in self._inflight if mine(key)
         )
 
     def _next_key(self, group=...):
@@ -460,23 +471,39 @@ class SegEngine:
         return keys[0]
 
     def next_cost(self, group: str | None = ...) -> int:
-        """Relation-(2) price of the micro-batch :meth:`step` would run
-        next (0 when idle).  The preemption point of the serving gateway:
-        a step whose price exceeds the class's remaining quantum is not
-        started — the quantum carries to the next round instead of the
-        step overdrafting it."""
-        key = self._next_key(group)
-        if key is None:
-            return 0
-        in_h, in_w, k = key[0], key[1], key[2]
-        n = min(len(self._tasks[key]), self.batch)
-        return n * self._tile_cycles(in_h, in_w, k)
+        """Relation-(2) price of the tiles the next :meth:`step` call with
+        this ``group`` emits (0 when idle): the oldest in-flight
+        micro-batch for an unscoped call, else the next one it launches;
+        every in-flight micro-batch plus the group's next one for a scoped
+        call.  The preemption point of the serving gateway: a step whose
+        price exceeds the class's remaining quantum is not started — the
+        quantum carries to the next round instead of the step overdrafting
+        it."""
+        batches = [(key, len(taken)) for key, taken, _ in self._inflight]
+        if group is ... and batches:
+            batches = batches[:1]
+        else:
+            key = self._next_key(group)
+            if key is not None:
+                batches.append((key, min(len(self._tasks[key]), self.batch)))
+        return sum(n * self._tile_cycles(*key[:3]) for key, n in batches)
 
     def step(self, group: str | None = ...) -> list[TileEvent]:
-        """Run one micro-batch and return its tile emissions (empty when
-        idle — falsy, so boolean call sites keep working).  ``group``
-        restricts the step to one scheduling group's tiles (the gateway's
-        class-quantum accounting); the default serves any group.
+        """Emit one micro-batch's tile events (empty when idle — falsy, so
+        boolean call sites keep working).  ``group`` restricts the step to
+        one scheduling group's tiles (the gateway's class-quantum
+        accounting); the default serves any group.
+
+        An unscoped call keeps one micro-batch dispatched ahead: it
+        launches the next micro-batch (two when nothing was in flight),
+        then blocks on the oldest one still on the device, stitches it and
+        returns its events.  The device computes the next batch while the
+        host fetches, stitches, admits and gathers, and every non-empty
+        return holds exactly one micro-batch, at most ``batch`` tiles of
+        one ``(in_h, in_w, klass)``; a batch is left in flight between
+        calls.  A scoped call leaves nothing in flight: it first collects
+        what unscoped calls left there and returns those events ahead of
+        its own micro-batch's.
 
         Group choice is the prioritization point: structure-first (lowest
         budget class; FIFO among equals via dict insertion order) under
@@ -484,66 +511,113 @@ class SegEngine:
         group runs next changes — group membership and within-group batch
         packing are fixed at admission — so emission order is scheduling
         policy, not numerics (see the ``priority`` docstring for the one
-        shared-scale caveat under slot churn).
+        shared-scale caveat under slot churn, where packing a batch one
+        step ahead can also move which requests share it).
         """
+        if group is not ...:
+            events: list[TileEvent] = []
+            while self._inflight:
+                key, taken, _ = self._inflight[0]
+                with self._step_span(key, taken):
+                    events += self._collect()
+            own = self._take(group)
+            if own is not None:
+                with self._step_span(*own):
+                    self._launch(*own)
+                    events += self._collect()
+            return events
+        # pack one micro-batch ahead of the oldest in flight, two when
+        # nothing is; the call emits the oldest
+        launch = [self._take(group) for _ in range(1 if self._inflight else 2)]
+        launch = [b for b in launch if b is not None]
+        if not (self._inflight or launch):
+            return []
+        with self._step_span(*(self._inflight or launch)[0][:2]):
+            for b in launch:
+                self._launch(*b)
+            return self._collect()
+
+    @staticmethod
+    def _step_span(key, taken):
+        return _span("step", in_h=key[0], in_w=key[1], klass=key[2],
+                     tiles=len(taken))
+
+    def _take(self, group):
+        """Pop the next micro-batch's tiles off the task table: ``(key,
+        [(request, tile_index), ...])``, or None when none wait."""
         key = self._next_key(group)
         if key is None:
-            return []
-        in_h, in_w, k = key[0], key[1], key[2]
+            return None
         task_group = self._tasks[key]
-        taken = task_group[: self.batch]
-        with _span("step", in_h=in_h, in_w=in_w, klass=k, tiles=len(taken)):
-            self._tasks[key] = task_group[self.batch :]
-            if not self._tasks[key]:
-                del self._tasks[key]
-            with _span("gather"):
-                x = np.zeros(
-                    (self.batch, in_h, in_w, self.cfg.in_ch), np.float32)
-                for b, (req, ti) in enumerate(taken):
-                    spec = req.plan.tiles[ti]
-                    x[b] = req.canvas_in[spec.y0 : spec.y1, spec.x0 : spec.x1]
-            with _span("upload"):
-                x_dev = jnp.asarray(x)
-            with _span("dispatch"):
-                out = self._fwd(self.params, x_dev, self.class_cfg(k))
-            with _span("fetch"):
-                out = np.asarray(out)
+        self._tasks[key] = task_group[self.batch :]
+        if not self._tasks[key]:
+            del self._tasks[key]
+        return key, task_group[: self.batch]
+
+    def _launch(self, key, taken) -> None:
+        """Gather, upload and dispatch one micro-batch, start its copy back
+        to the host, and queue it for :meth:`_collect`."""
+        in_h, in_w, k = key[0], key[1], key[2]
+        with _span("gather"):
+            x = np.zeros((self.batch, in_h, in_w, self.cfg.in_ch), np.float32)
+            for b, (req, ti) in enumerate(taken):
+                spec = req.plan.tiles[ti]
+                x[b] = req.canvas_in[spec.y0 : spec.y1, spec.x0 : spec.x1]
+        with _span("upload"):
+            x_dev = jnp.asarray(x)
+        with _span("dispatch"):
+            out = self._fwd(self.params, x_dev, self.class_cfg(k))
+            out.copy_to_host_async()  # starts as soon as the compute ends
+            # release buffers inside a phase's span, so that the step's
+            # own time stays bookkeeping (so ``del out`` in the stitch)
+            del x_dev
+            self.counters.launched_ahead += bool(self._inflight)
+            self.counters.upload_bytes += x.nbytes
+            self._inflight.append((key, taken, out))
+
+    def _collect(self) -> list[TileEvent]:
+        """Block on the oldest in-flight micro-batch, stitch it and return
+        its tile events."""
+        with _span("fetch"):
+            key, taken, out = self._inflight.popleft()
+            out = np.asarray(out)
+        in_h, in_w, k = key[0], key[1], key[2]
+        with _span("stitch"):
             c = self.counters
             c.steps += 1
             c.tiles += len(taken)
             c.tile_slots += self.batch
             c.window_pixels += len(taken) * in_h * in_w
             c.host_syncs += 1
-            c.upload_bytes += x.nbytes
             c.fetch_bytes += out.nbytes
             self._signatures.add((in_h, in_w, k))
             c.executables = len(self._signatures)
-            with _span("stitch"):
-                events: list[TileEvent] = []
-                cyc = self._tile_cycles(in_h, in_w, k)  # one price, both accounts
-                pj = self._tile_pj(in_h, in_w, k)
-                for b, (req, ti) in enumerate(taken):
-                    spec = req.plan.tiles[ti]
-                    cy, cx = spec.crop
-                    req.canvas_out[
-                        spec.core_y0 : spec.core_y1, spec.core_x0 : spec.core_x1
-                    ] = out[b][cy, cx]
-                    req.cycles += cyc
-                    req.pj += pj
-                    req.remaining -= 1
-                    req.emitted.append(ti)
-                    if req.remaining == 0:
-                        self._finish(req)
-                    events.append(
-                        TileEvent(
-                            rid=req.rid, tile=ti, klass=k, cycles=cyc,
-                            core=(
-                                spec.core_y0, spec.core_x0,
-                                spec.core_y1, spec.core_x1,
-                            ),
-                            done=req.done, request=req, pj=pj,
-                        )
+            events: list[TileEvent] = []
+            cyc = self._tile_cycles(in_h, in_w, k)  # one price, both accounts
+            pj = self._tile_pj(in_h, in_w, k)
+            for b, (req, ti) in enumerate(taken):
+                spec = req.plan.tiles[ti]
+                cy, cx = spec.crop
+                req.canvas_out[
+                    spec.core_y0 : spec.core_y1, spec.core_x0 : spec.core_x1
+                ] = out[b][cy, cx]
+                req.cycles += cyc
+                req.pj += pj
+                req.remaining -= 1
+                req.emitted.append(ti)
+                if req.remaining == 0:
+                    self._finish(req)
+                events.append(
+                    TileEvent(
+                        rid=req.rid, tile=ti, klass=k, cycles=cyc,
+                        core=(
+                            spec.core_y0, spec.core_x0,
+                            spec.core_y1, spec.core_x1,
+                        ),
+                        done=req.done, request=req, pj=pj,
                     )
+                )
+            del out
         return events
 
     def compiled_texts(self) -> dict[tuple[int, int, int], str]:
@@ -596,10 +670,16 @@ class SegEngine:
         Under ``priority=True`` each image's structure-class tiles stream
         out before its background tiles; consume ``event.request.partial()``
         for the stitch so far and ``event.request.result`` once
-        ``event.done``.  Equivalent to :meth:`run` in final outputs."""
+        ``event.done``.  Equivalent to :meth:`run` in final outputs.
+
+        Each round admits what the free slots allow, then runs one
+        unscoped :meth:`step`: while the host fetches and stitches
+        micro-batch n, admits, and gathers n + 2, the device computes
+        n + 1.  The stream ends with nothing queued, admitted or in
+        flight."""
         for im in images:
             self.submit(im)
-        while self.queue or self.slots.any_active() or self._tasks:
+        while self.queue or self.slots.any_active() or self.has_work():
             self.queue.pump(self.slots, self._admit)
             events = self.step()
             if not events and not self.queue:

@@ -320,6 +320,41 @@ def test_fresh_plan_admits_and_serves():
     assert gw.stats()["fallbacks"] == {}
 
 
+def test_preempted_seg_round_emits_its_in_flight_batch_next_round():
+    """An unscoped preemptive ``SegAdapter.work`` round keeps one
+    micro-batch dispatched ahead; when the quantum runs out with it still
+    on the device, the next round emits it first.  Every step is charged
+    exactly what ``next_cost`` priced before it."""
+    from repro.serve.gateway import SegAdapter
+
+    cfg, params = _small_unet()
+    adapter = SegAdapter(cfg, params, tile=8, batch=2)
+    eng = adapter.engine
+    priced = []
+    next_cost, step = eng.next_cost, eng.step
+
+    def priced_step(*args):
+        priced.append(next_cost(*args))
+        return step(*args)
+
+    eng.step = priced_step
+    eng.submit(np.linspace(0, 1, 32 * 32 * 2, dtype=np.float32)
+               .reshape(32, 32, 2))
+    eng.queue.pump(eng.slots, eng._admit)
+    first = eng.next_cost()
+    used1, _, ev1 = adapter.work(first, qos=None)
+    assert used1 == first and ev1
+    [(_, in_flight, _)] = eng._inflight  # launched ahead, not yet collected
+    assert eng.has_work()
+    used2, _, ev2 = adapter.work(10**12, qos=None)
+    assert [(e.rid, e.tile) for e in ev2[: len(in_flight)]] == [
+        (req.rid, ti) for req, ti in in_flight]
+    assert not eng.has_work()
+    assert used1 + used2 == sum(priced) == sum(e.cycles for e in ev1 + ev2)
+    assert len(priced) == eng.counters.steps
+    assert eng.counters.launched_ahead > 0
+
+
 def test_stale_plan_falls_back_to_uniform_schedule():
     from repro.serve.gateway import SegAdapter
 
