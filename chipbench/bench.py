@@ -14,7 +14,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from chipbench import check, generator, geometry, images, model, spec
+from chipbench import check, generator, geometry, images, spec
 from chipbench import trace as tracing
 
 GRACE_S = 60.0  # how long past the window's close an answer may come
@@ -93,15 +93,14 @@ def record_steps(engine, win_ref: list, clock=time.perf_counter) -> None:
 def warm(engine, conf: dict, pool: list) -> int:
     """Serve the fewest pool images that hit every (window shape, class
     schedule) the pool's tiles hit; returns how many were served."""
-    depth, base = conf["model"]["depth"], conf["plane_schedule"]
+    arch, base = spec.arch(conf), conf["plane_schedule"]
     need, hits = set(), []
     for img in pool:
-        cv = geometry.canvas(img, depth)
+        cv = arch.canvas(img, conf)
         amax = float(np.max(np.abs(cv)))
         keys = {(t.shape, geometry.class_planes(
                     base, geometry.budget_class(cv[t.y0:t.y1, t.x0:t.x1], amax)))
-                for t in geometry.plan(img.shape[0], img.shape[1], depth=depth,
-                                       tile=conf["tile"], halo=conf["halo"])}
+                for t in arch.plan(img.shape[0], img.shape[1], conf)}
         hits.append(keys)
         need |= keys
     chosen = []
@@ -228,10 +227,11 @@ def setup(conf: dict, seed: int) -> Served:
     """Weights, images, the engine and its warm-up."""
     import jax
 
-    params = model.make_params(conf["model"], seed)
+    arch = spec.arch(conf)
+    params = arch.make_params(conf["model"], seed)
     jax.block_until_ready(params)
     pool = images.pool(conf, seed)
-    engine = model.make_engine(conf, params)
+    engine = arch.make_engine(conf, params)
     win_ref = [Window()]
     record_steps(engine, win_ref)
     n_warm = warm(engine, conf, pool)

@@ -14,8 +14,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from chipbench import geometry, rng
-from chipbench import reference as ref
+from chipbench import geometry, rng, spec
 
 _SAMPLE_TAG = 0x5A3B1E
 
@@ -33,16 +32,15 @@ class Reference:
         self.params = params
         self.image_of = image_of  # rid -> (H, W, C) image
         self.batch = batch
-        self.depth = conf["model"]["depth"]
+        self.arch = spec.arch(conf)
         self._prep: dict = {}
 
     def prepared(self, rid: int):
         """(canvas, {core: Tile}, {core: class}) of one image."""
         if rid not in self._prep:
             img = self.image_of[rid]
-            cv = geometry.canvas(img, self.depth)
-            tiles = geometry.plan(img.shape[0], img.shape[1], depth=self.depth,
-                                  tile=self.conf["tile"], halo=self.conf["halo"])
+            cv = self.arch.canvas(img, self.conf)
+            tiles = self.arch.plan(img.shape[0], img.shape[1], self.conf)
             amax = float(np.max(np.abs(cv)))
             self._prep[rid] = (
                 cv, {t.core: t for t in tiles},
@@ -58,8 +56,8 @@ class Reference:
         n_cls = self.conf["model"]["n_classes"]
         out = {}
         for rid in rids:
-            ph, pw = geometry.canvas_shape(*self.image_of[rid].shape[:2],
-                                           self.depth)
+            ph, pw = self.arch.canvas_shape(*self.image_of[rid].shape[:2],
+                                            self.conf)
             out[rid] = np.full((ph, pw, n_cls), np.nan, np.float32)
         base = self.conf["plane_schedule"]
         for step in steps:
@@ -76,14 +74,13 @@ class Reference:
                 shapes = {t.shape for _, t in tiles}
                 if len(classes) != 1 or len(shapes) != 1 or len(tiles) > self.batch:
                     continue
-                (h, w), = shapes
-                c = self.conf["model"]["in_ch"]
-                x = np.zeros((self.batch, h, w, c), np.float32)
-                for b, (rid, t) in enumerate(tiles):
-                    x[b] = self.prepared(rid)[0][t.y0:t.y1, t.x0:t.x1]
+                windows = [self.prepared(rid)[0][t.y0:t.y1, t.x0:t.x1]
+                           for rid, t in tiles]
+                x = np.zeros((self.batch,) + windows[0].shape, np.float32)
+                x[:len(windows)] = windows
                 planes = np.asarray(geometry.class_planes(base, classes.pop()),
                                     np.int32)
-                y = np.asarray(ref.forward(self.params, x, planes, bits=bits))
+                y = np.asarray(self.arch.forward(self.params, x, planes, bits=bits))
                 for b, (rid, t) in enumerate(tiles):
                     if rid in want:
                         cy0, cx0, cy1, cx1 = t.core

@@ -1,13 +1,15 @@
-"""Shape arithmetic of the served U-Net, kept with the benchmark.
+"""Shape arithmetic of the segmentation server that every architecture
+shares, kept with the benchmark.
 
 Copied from the program (``repro.segserve.tiling``, ``repro.segserve.
-adaptive``, ``repro.core.plane_schedule.PlaneSchedule.refine`` and
-``repro.core.cycle_model.unet_conv_layers``) so that a change there cannot
-move what the benchmark counts or what its reference computes:
+adaptive`` and ``repro.core.plane_schedule.PlaneSchedule.refine``) so that a
+change there cannot move what the benchmark counts or what its reference
+computes:
 
-* the exact halo and the tile plan of an image;
+* a tile of an image's plan (each architecture plans its own,
+  ``chipbench/archs/``);
 * each tile's budget class and the plane schedule the class runs;
-* the 3x3 convs of a forward, and their operations and bytes.
+* a 3x3 conv's operations and bytes, and a whole image's operations.
 """
 from __future__ import annotations
 
@@ -18,21 +20,6 @@ import numpy as np
 
 N_BITS = 8
 MAX_CLASS = 6
-
-
-def halo_for(depth: int, convs_per_stage: int) -> int:
-    """Exact halo (input pixels per side) of an artificial tile edge,
-    rounded up to a multiple of ``2**depth``."""
-    m, skips = 0, []
-    for _ in range(depth):
-        m += convs_per_stage
-        skips.append(m)
-        m = -(-m // 2)
-    m += convs_per_stage
-    for level in reversed(range(depth)):
-        m = max(2 * m, skips[level]) + convs_per_stage
-    mult = 2**depth
-    return -(-max(m, 1) // mult) * mult
 
 
 @dataclass(frozen=True)
@@ -46,32 +33,6 @@ class Tile:
     @property
     def shape(self) -> tuple[int, int]:
         return (self.y1 - self.y0, self.x1 - self.x0)
-
-
-def canvas_shape(h: int, w: int, depth: int) -> tuple[int, int]:
-    mult = 2**depth
-    return (-(-h // mult) * mult, -(-w // mult) * mult)
-
-
-def plan(h: int, w: int, *, depth: int, tile: int, halo: int) -> list[Tile]:
-    """Cores of ``tile`` striding the padded canvas, each dilated by the
-    halo and clipped to the canvas."""
-    ph, pw = canvas_shape(h, w, depth)
-    out = []
-    for cy in range(0, ph, tile):
-        ch = min(tile, ph - cy)
-        for cx in range(0, pw, tile):
-            cw = min(tile, pw - cx)
-            out.append(Tile(max(0, cy - halo), max(0, cx - halo),
-                            min(ph, cy + ch + halo), min(pw, cx + cw + halo),
-                            (cy, cx, cy + ch, cx + cw)))
-    return out
-
-
-def canvas(image: np.ndarray, depth: int) -> np.ndarray:
-    ph, pw = canvas_shape(image.shape[0], image.shape[1], depth)
-    return np.pad(image.astype(np.float32),
-                  ((0, ph - image.shape[0]), (0, pw - image.shape[1]), (0, 0)))
 
 
 def budget_class(window: np.ndarray, canvas_amax: float) -> int:
@@ -126,31 +87,8 @@ class Conv:
                 + 4 * self.n * self.h * self.w * self.cout)
 
 
-def convs(model: dict, n: int, h: int, w: int) -> list[Conv]:
-    """The 3x3 convs of one forward over ``n`` windows of ``h x w``, in
-    forward order (encoder, bottleneck, decoder)."""
-    c, base, depth = model["in_ch"], model["base"], model["depth"]
-    per = model["convs_per_stage"]
-    out, skips = [], []
-    for d in range(depth):
-        cout = base * 2**d
-        out.append(Conv(n, h, w, c, cout))
-        out += [Conv(n, h, w, cout, cout)] * (per - 1)
-        skips.append(cout)
-        c, h, w = cout, h // 2, w // 2
-    cout = base * 2**depth
-    out.append(Conv(n, h, w, c, cout))
-    out += [Conv(n, h, w, cout, cout)] * (per - 1)
-    c = cout
-    for d in reversed(range(depth)):
-        h, w, cout = h * 2, w * 2, skips[d]
-        out.append(Conv(n, h, w, cout + c, cout))
-        out += [Conv(n, h, w, cout, cout)] * (per - 1)
-        c = cout
-    return out
-
-
-def model_ops(model: dict, h: int, w: int) -> int:
-    """Operations of the 3x3 convs over one whole ``h x w`` image: each
-    multiply-add counted once, no halo, no planes, no padding."""
-    return sum(cv.ops for cv in convs(model, 1, h, w))
+def model_ops(arch, model: dict, h: int, w: int) -> int:
+    """Operations of the layers of ``arch`` (a module of ``chipbench/archs``)
+    over one whole ``h x w`` image: each multiply-add counted once, no halo,
+    no planes, no padding."""
+    return sum(layer.ops for layer in arch.layers(model, 1, h, w))

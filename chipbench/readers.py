@@ -5,15 +5,13 @@ a number, or ``None`` where the run holds nothing to read.
 """
 from __future__ import annotations
 
-from chipbench import geometry
+from chipbench import spec
 from chipbench import trace as tracing
 
 
 def tiles_by_core(ctx) -> dict:
     h, w, _ = ctx.conf["image"]
-    return {t.core: t for t in geometry.plan(
-        h, w, depth=ctx.conf["model"]["depth"], tile=ctx.conf["tile"],
-        halo=ctx.conf["halo"])}
+    return {t.core: t for t in spec.arch(ctx.conf).plan(h, w, ctx.conf)}
 
 
 def batch_fill(ctx) -> float | None:
@@ -32,17 +30,18 @@ def idle_share(ctx) -> float | None:
     return 100.0 * (1.0 - s["busy_s"] / s["window_s"])
 
 
-def least_conv_seconds(ctx) -> float:
-    """The window's 3x3 convs at the chip's peaks: for each micro-batch
-    run, each conv's larger of ops over the int8 peak and bytes over the
-    HBM bandwidth."""
+def least_layer_seconds(ctx) -> float:
+    """The window's layers at the chip's peaks: for each micro-batch run,
+    each layer's larger of ops over the int8 peak and bytes over the HBM
+    bandwidth."""
     by_core = tiles_by_core(ctx)
+    layers = spec.arch(ctx.conf).layers
     total = 0.0
     for step in ctx.window_steps():
         h, w = by_core[step.tiles[0][1]].shape
-        for cv in geometry.convs(ctx.conf["model"], ctx.batch, h, w):
-            total += max(cv.ops / ctx.peaks["int8_ops"],
-                         cv.bytes / ctx.peaks["hbm_bytes_per_s"])
+        for layer in layers(ctx.conf["model"], ctx.batch, h, w):
+            total += max(layer.ops / ctx.peaks["int8_ops"],
+                         layer.bytes / ctx.peaks["hbm_bytes_per_s"])
     return total
 
 

@@ -1,8 +1,9 @@
 """Where the benchmark's data lives, found by name.
 
-A cell is ``workloads/<cell>.json``, a configuration ``configs/<name>.json``
-and a metric a reader ``metrics/<name>.py`` with ``read(ctx)``.  Adding one
-is adding files: nothing here lists them.
+A cell is ``workloads/<cell>.json``, a configuration ``configs/<name>.json``,
+the architecture a configuration's ``"arch"`` names a module
+``archs/<arch>.py``, and a metric a reader ``metrics/<name>.py`` with
+``read(ctx)``.  Adding one is adding files: nothing here lists them.
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ from pathlib import Path
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 _NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+_ARCH = re.compile(r"^[A-Za-z_][A-Za-z0-9_]{0,63}$")
 
 
 def _checked(name: str) -> str:
@@ -37,6 +39,20 @@ def cell(name: str) -> dict:
 
 def config(name: str) -> dict:
     return load_json(HERE / "configs" / f"{_checked(name)}.json")
+
+
+def arch(conf: dict):
+    """The module ``archs/<arch>.py`` that the configuration's ``"arch"``
+    names, with the interface ``archs/__init__.py`` gives.  A configuration
+    without ``"arch"``, or one naming no module there, is an error."""
+    if "arch" not in conf:
+        raise KeyError(f"configuration {conf.get('name')!r} has no \"arch\" key")
+    name = conf["arch"]
+    if not _ARCH.match(name) or not (HERE / "archs" / f"{name}.py").is_file():
+        raise ModuleNotFoundError(
+            f"configuration {conf.get('name')!r} names the architecture "
+            f"{name!r}, and chipbench/archs/{name}.py does not exist")
+    return importlib.import_module(f"chipbench.archs.{name}")
 
 
 def metrics_for(bench: dict, cell_name: str, traced: bool) -> list[dict]:

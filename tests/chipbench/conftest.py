@@ -1,5 +1,6 @@
 """Shared helpers of the benchmark's tests: the repository root on the
-path, and tiny copies of each cell that the CPU can run."""
+path, the cells and configurations as ``BENCHMARK.json`` lists them, and
+tiny copies of each that the CPU can run."""
 import sys
 from pathlib import Path
 
@@ -10,24 +11,18 @@ for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from chipbench import geometry, spec  # noqa: E402
+from chipbench import spec  # noqa: E402
 
-# each configuration's tiny stand-in: its depth, convs per stage, classes,
-# channels, datapath and schedule kind, at widths and sizes a CPU can run
-_TINY = {
-    "unet48_brats240": {"base": 8, "image": [48, 48], "tile": 32, "pool": 5},
-    "unet64_kits512": {"base": 4, "image": [64, 64], "tile": 56, "pool": 4},
-}
+# each configuration's tiny stand-in, tiny/<config>.json: the sizes its
+# architecture's ``tiny`` cuts it to
+TINY = Path(__file__).resolve().parent / "tiny"
+CELLS = [w["name"] for w in spec.benchmark()["workloads"]]
+CONFIGS = [c["name"] for c in spec.benchmark()["configs"]]
 
 
 def tiny_conf(name: str) -> dict:
     conf = spec.config(name)
-    t = _TINY[name]
-    m = {**conf["model"], "base": t["base"], "depth": 2}
-    n_convs = 2 * m["depth"] * m["convs_per_stage"] + m["convs_per_stage"]
-    return {**conf, "model": m, "plane_schedule": conf["plane_schedule"][:1] * n_convs,
-            "tile": t["tile"], "halo": geometry.halo_for(2, m["convs_per_stage"]),
-            "image": t["image"] + conf["image"][2:], "pool": t["pool"]}
+    return spec.arch(conf).tiny(conf, spec.load_json(TINY / f"{name}.json"))
 
 
 def tiny_cell(name: str) -> tuple[dict, dict]:

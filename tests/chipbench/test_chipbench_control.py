@@ -9,17 +9,17 @@ import time
 
 import jax.numpy as jnp
 import pytest
-from conftest import tiny_cell
+from conftest import CELLS, tiny_cell
 
-from chipbench import bench, model, spec
+from chipbench import bench, spec
 
-# each cell's own traffic, and the first cell under an open-loop mix
-CASES = {"unet48_brats240.backlog": None, "unet64_kits512.backlog": None,
-         "unet48_brats240.open_loop": {"name": "open_loop", "rate_per_s": 32.0}}
+# each cell under its own traffic, and the first cell under an open-loop mix
+OPEN_LOOP = {"name": "open_loop", "rate_per_s": 32.0}
+CASES = {**{c: (c, None) for c in CELLS},
+         CELLS[0].rsplit(".", 1)[0] + ".open_loop": (CELLS[0], OPEN_LOOP)}
 
 
-@pytest.mark.parametrize("cell_name", ["unet48_brats240.backlog",
-                                       "unet64_kits512.backlog"])
+@pytest.mark.parametrize("cell_name", CELLS)
 def test_the_int4_control_reads_above_the_limit(cell_name):
     cell, conf = tiny_cell(cell_name)
     sv = bench.setup(conf, 2**35 + 3)
@@ -48,18 +48,19 @@ def _altered(fwd):
 @pytest.mark.parametrize("case", list(CASES))
 def test_a_broken_served_path_is_not_correct(case, fault, monkeypatch,
                                              bench_json):
-    make = model.make_engine
+    cell_name, traffic = CASES[case]
+    cell, conf = tiny_cell(cell_name)
+    if traffic:
+        cell = {**cell, "traffic": traffic}
+    arch = spec.arch(conf)
+    make = arch.make_engine
 
     def broken_engine(conf, params):
         engine = make(conf, params)
         engine._fwd = fault(engine._fwd)
         return engine
 
-    monkeypatch.setattr(model, "make_engine", broken_engine)
-    cell_name = case.rsplit(".", 1)[0] + ".backlog"
-    cell, conf = tiny_cell(cell_name)
-    if CASES[case]:
-        cell = {**cell, "traffic": CASES[case]}
+    monkeypatch.setattr(arch, "make_engine", broken_engine)
     out = bench.run(cell_name, cell, conf,
                     spec.metrics_for(bench_json, cell_name, False),
                     seed=2**31 + 17, seconds=0.5, traced=False,

@@ -8,7 +8,7 @@ from pathlib import Path
 from types import SimpleNamespace
 
 import pytest
-from conftest import tiny_cell
+from conftest import CELLS, tiny_cell
 
 from chipbench import phases, readers, spec
 from chipbench import trace as tracing
@@ -173,7 +173,7 @@ def test_a_traced_tiny_window_on_the_cpu():
     """The whole measurement path on the CPU: the spans reach the trace,
     one per counted step and admission, and the device metrics stay
     silent, since the CPU trace has no device plane."""
-    name = "unet48_brats240.backlog"
+    name = CELLS[0]
     cell, conf = tiny_cell(name)
     out, tr = phases.measure(name, cell, conf, seed=2**40 + 13, seconds=1.0,
                              t_start=time.perf_counter())

@@ -10,15 +10,16 @@ import sys
 import time
 
 import pytest
-from conftest import ROOT, tiny_cell
+from conftest import CELLS, ROOT, tiny_cell
 
 from chipbench import bench, spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-CELLS = ["unet48_brats240.backlog", "unet64_kits512.backlog"]
-BENCH_CELLS = CELLS
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# what every module under chipbench/archs/ gives (chipbench/archs/__init__.py)
+ARCH_API = ("make_params", "make_engine", "forward", "canvas_shape", "canvas",
+            "plan", "layers", "tiny")
 
 
 def test_benchmark_json_keeps_to_the_contract(bench_json):
@@ -28,12 +29,17 @@ def test_benchmark_json_keeps_to_the_contract(bench_json):
     assert b["command"] == ["python3", "chipbench/run.py"]
     assert b["paths"] == ["chipbench", "tests/chipbench"]
     assert 1 <= b["run_seconds"] <= 51
-    assert [w["name"] for w in b["workloads"]] == BENCH_CELLS
+    assert b["configs"] and b["workloads"]
     for c in b["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert NAME.match(c["name"]) and c["reduced"] == []
-        assert spec.config(c["name"])["source"] == c["source"]
+        assert NAME.match(c["name"]) and isinstance(c["reduced"], list)
+        assert all(isinstance(k, str) and NAME.match(k) for k in c["reduced"])
+        conf = spec.config(c["name"])
+        assert conf["source"] == c["source"]
         assert c["file"] == f"chipbench/configs/{c['name']}.json"
+        arch = spec.arch(conf)
+        assert all(callable(getattr(arch, f, None)) for f in ARCH_API)
+    assert len({c["name"] for c in b["configs"]}) == len(b["configs"])
     e2e = {m["name"] for m in b["end_to_end"]}
     assert e2e == {"images_per_s", "setup_s"}
     for w in b["workloads"]:
@@ -41,22 +47,27 @@ def test_benchmark_json_keeps_to_the_contract(bench_json):
         cell = spec.cell(w["name"])
         assert (cell["config"], cell["chips"], cell["why"]) == (
             w["config"], w["chips"], w["why"])
-        assert w["traffic"] == cell["traffic"]["name"] and w["chips"] == 1
+        assert w["traffic"] == cell["traffic"]["name"] and w["chips"] in (1, 4)
         assert len(w["why"]) <= 200
         got_e2e = {m["name"] for m in spec.metrics_for(b, w["name"], False)}
         assert "setup_s" in got_e2e and len(got_e2e) >= 2
         assert spec.metrics_for(b, w["name"], True)
+    assert len(set(CELLS)) == len(CELLS)
+    assert len({(w["config"], w["traffic"]) for w in b["workloads"]}) == len(CELLS)
+    assert {w["config"] for w in b["workloads"]} == {c["name"] for c in b["configs"]}
+    four = sum(w["chips"] == 4 for w in b["workloads"])
+    assert four <= max(1, len(CELLS) // 2)
     for m in b["end_to_end"] + b["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
-        assert set(m.get("workloads", BENCH_CELLS)) <= set(BENCH_CELLS)
+        assert set(m.get("workloads", CELLS)) <= set(CELLS)
         assert callable(spec.reader(m["name"]))
     for m in b["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25 and m["source"] == "host_clock"
     for m in b["per_layer"]:
         assert m["moves"] in e2e and "bound" not in m
         moved = next(e for e in b["end_to_end"] if e["name"] == m["moves"])
-        assert set(m["workloads"]) <= set(moved.get("workloads", BENCH_CELLS))
+        assert set(m["workloads"]) <= set(moved.get("workloads", CELLS))
 
 
 @pytest.mark.parametrize("cell_name", CELLS)
@@ -84,7 +95,7 @@ def test_cell_rehearses_on_the_cpu(cell_name, bench_json, capsys):
 def test_an_open_loop_mix_rehearses_on_the_cpu(bench_json):
     """A mix that is only data (open-loop arrivals, here in groups of two)
     runs through the same harness with no code of its own."""
-    cell_name = "unet48_brats240.backlog"
+    cell_name = CELLS[0]
     cell, conf = tiny_cell(cell_name)
     cell = {**cell, "traffic": {"name": "open_loop", "rate_per_s": 4.0, "group": 2}}
     out = bench.run(cell_name, cell, conf,
@@ -101,7 +112,7 @@ def _run_cli(cwd, env_extra=None):
     env.pop("PYTHONPATH", None)
     return subprocess.run(
         [sys.executable, "chipbench/run.py", "--workload",
-         "unet48_brats240.backlog", "--seed", str(2**33 + 5), "--seconds", "1",
+         CELLS[0], "--seed", str(2**33 + 5), "--seconds", "1",
          "--trace", "0"], cwd=cwd, env=env, capture_output=True, text=True,
         timeout=300)
 
