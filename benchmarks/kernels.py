@@ -58,8 +58,8 @@ def run() -> list[tuple[str, float, str]]:
     # tensor ever touches HBM); the cascade materializes one full (M,N)
     # partial product per plane.  We count materialized dot outputs in the
     # optimized HLO of each.  ``ops`` runs the kernel in interpret mode off
-    # the TPU, where its body is inlined and its 8 per-plane dots appear as
-    # XLA dots; on the TPU the pallas_call is ONE custom call and only
+    # the TPU, where its body is inlined and its one int8 dot per block
+    # appears as an XLA dot; on the TPU the pallas_call is ONE custom call and only
     # out_specs' (M,N) int32 tile ever reaches HBM (by construction).
     from repro.kernels import ops
 

@@ -3,10 +3,11 @@
 ``mma_dot`` computes an exact (or plane-truncated) int8 x int8 -> int32
 matmul through one of four datapaths:
 
-  impl='pallas'   the fused Pallas kernel (kernels/mma_matmul.py): bit-plane
-                  Horner recurrence with the residual held in VMEM — the
-                  TPU-native merged unit (single "initial delay" = one HBM
-                  read of x and w).                       [paper's proposal]
+  impl='pallas'   the fused Pallas kernel (kernels/mma_matmul.py): the
+                  plane-truncated operand in one int8 MXU pass per block,
+                  accumulator held in VMEM — the TPU-native merged unit
+                  (single "initial delay" = one HBM read of x and w).
+                                                          [paper's proposal]
   impl='xla'      same recurrence in pure XLA (lax.scan over planes).
   impl='cascade'  per-plane partials materialized then tree-reduced — the
                   un-merged baseline with per-stage round-trips. [baseline]

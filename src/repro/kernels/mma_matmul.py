@@ -1,30 +1,36 @@
-"""Pallas TPU kernel: the merged multiply-add (MMA) as a fused bit-plane matmul.
+"""Pallas TPU kernel: the merged multiply-add (MMA) as one fused int8 matmul.
 
 FPGA -> TPU mapping (DESIGN.md Sec. 2).  The FPGA MMA streams activation bits
 MSB-first through an AND array and keeps a left-shifted *residual* inside the
 unit, so the whole inner product pays one initial delay.  The TPU analogue of
 "initial delay" is an HBM round-trip: an un-fused bit-plane implementation
 writes 8 plane partial products to HBM and re-reads them to reduce.  This
-kernel keeps the Horner accumulator (the residual) in VMEM scratch for the
-whole (bm, bn) output tile: x and w are read from HBM exactly once, partial
-sums never leave VMEM — the merged pipeline.
+kernel keeps the int32 accumulator in VMEM scratch for the whole (bm, bn)
+output tile: x and w are read from HBM once per tile, partial sums never
+leave VMEM — the merged pipeline.
 
-Datapath per grid step (m, n, k):
-    u      = x_block + 128                 (offset two's-complement -> 0..255)
-    acc    = 0
-    for b in MSB..(MSB-planes+1):          (static unroll, 8 iterations max)
-        plane = (u >> b) & 1               (VPU)
-        acc   = 2*acc + plane @ w_block    (MXU, bf16 x bf16 -> f32)
-    acc   *= 2**dropped                    (early-termination rescale)
-    acc   -= 128 * colsum(w_block)         (exact signed correction)
-    out   += acc                           (k-accumulation in VMEM scratch,
-                                            written to HBM on the last k step)
+The Horner sum over the ``planes`` most significant digits of the offset
+activation ``u = x + 128`` collapses into one product on a masked operand
+(``core.bitplane.truncate_to_planes``):
 
-Exactness of the bf16 MXU path: plane is {0,1} (exact), |w| <= 127 needs 7
-mantissa bits (bf16 has 8 -> exact), products accumulate in f32 with
-|partial| <= K * 127 * 255 < 2^24 for K <= 512 per block (exact f32 ints).
-The k-grid accumulation is int32.  dimension_semantics marks m, n parallel
-and k arbitrary (sequential accumulation).
+    sum_i 2^(7-i) * plane_i(u) @ w  -  128 * colsum(w)  ==  (x & m_p) @ w,
+    m_p = -(1 << (8 - planes))  as int8
+
+so the kernel makes one MXU pass per block whatever ``planes`` is.  Datapath
+per grid step (m, n, k):
+    x'   = x_block & m_p                   (VPU, one AND per packed int32
+                                            word of four int8 lanes; no mask
+                                            at planes = 8)
+    out += x' @ w_block                    (MXU, int8 x int8 -> int32)
+and the int32 accumulator is written to HBM on the last k step.
+
+Exactness: x' and w are plain int8, every product and partial sum is an
+int32 integer, so the result is bit-exact for any block shape and for
+K < 2^17 (|x' * w| <= 2^14).  Unsigned activations (``signed=False``, x
+holds the bits of u in 0..255) take the offset form
+``((u & m_p) - 128) @ w + 128 * colsum(w)``, where ``(u & m_p) - 128`` is
+``x' ^ 0x80`` read as int8.  dimension_semantics marks m, n parallel and k
+arbitrary (sequential accumulation).
 """
 from __future__ import annotations
 
@@ -37,10 +43,41 @@ from jax.experimental.pallas import tpu as pltpu
 
 N_BITS = 8
 
-# Default MXU-aligned tile shapes: (bm x bk) int8 + (bk x bn) int8 + f32/i32
-# accumulators comfortably fit VMEM (~16 MiB/core on v5e):
-#   x: 128*512 = 64 KiB, w: 512*128 = 64 KiB, acc: 128*128*4*2 = 128 KiB.
+# Default MXU-aligned tile shapes for callers that give none; ``ops`` picks
+# its blocks with ``block_shape``.
 BM, BK, BN = 128, 512, 128
+# With one int8 pass per block the kernel is bound by HBM, not the MXU: x is
+# re-read once per column block and w once per row block, so blocks are as
+# large as the problem and VMEM allow.  At the largest, 512 x 2048 int8 of x,
+# 2048 x 512 int8 of w and 512 x 512 int32 of accumulator and output, double
+# buffered, take about 7 MiB of v5e's 16 MiB.
+BM_MAX, BK_WHOLE, BN_MAX = 512, 2048, 512
+
+
+def pad_to(v: int, b: int) -> int:
+    return (v + b - 1) // b * b
+
+
+def block_shape(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """(bm, bk, bn) for an (m, k) @ (k, n) problem, from its shape alone.
+
+    Rows come in blocks of 512 (fewer re-reads of w), or the whole of a
+    smaller m rounded up to int8's 32-row tile.  A contraction of at most
+    2048, rounded up to the 128-lane tile, is one block, so x is read once
+    for all column blocks; a longer one goes in blocks of 512.  Columns,
+    rounded up to 128, are one block up to 512, so x is read once per row
+    block.
+    """
+    bm = min(BM_MAX, pad_to(m, 32))
+    bk = pad_to(k, 128) if pad_to(k, 128) <= BK_WHOLE else BK
+    bn = min(pad_to(n, 128), BN_MAX)
+    return bm, bk, bn
+
+
+def _byte_word(byte: int) -> int:
+    """``byte`` repeated in the four bytes of an int32 word (as a signed int)."""
+    word = (byte & 0xFF) * 0x01010101
+    return word - (1 << 32) if word >= 1 << 31 else word
 
 
 def _mma_kernel(x_ref, w_ref, *refs, planes: int, signed: bool, n_k: int,
@@ -55,27 +92,22 @@ def _mma_kernel(x_ref, w_ref, *refs, planes: int, signed: bool, n_k: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    u = x_ref[...].astype(jnp.int32)
-    if signed:
-        u = u + 128
-    w = w_ref[...].astype(jnp.bfloat16)
+    x = x_ref[...]
+    if planes < N_BITS or not signed:
+        # mask on int32 words: four int8 lanes per VPU op, no widening
+        words = pltpu.bitcast(x, jnp.int32)
+        if planes < N_BITS:
+            words = words & _byte_word(-(1 << (N_BITS - planes)))
+        if not signed:
+            words = words ^ _byte_word(0x80)
+        x = pltpu.bitcast(words, jnp.int8)
 
-    acc = jnp.zeros(acc_ref.shape, jnp.float32)
-    for i in range(planes):
-        b = N_BITS - 1 - i  # MSB first — the digit-serial streaming order
-        plane = ((u >> b) & 1).astype(jnp.bfloat16)
-        part = jax.lax.dot_general(
-            plane, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        acc = acc * 2.0 + part  # the left-shifted residual recurrence
-
-    dropped = N_BITS - planes
-    acc = acc * float(2**dropped)
-    if signed:
-        colsum = jnp.sum(w_ref[...].astype(jnp.int32), axis=0, keepdims=True)
-        acc = acc - 128.0 * colsum.astype(jnp.float32)
-
-    acc_ref[...] += acc.astype(jnp.int32)
+    acc = jax.lax.dot_general(
+        x, w_ref[...], (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+    )
+    if not signed:
+        acc = acc + 128 * jnp.sum(w_ref[...].astype(jnp.int32), axis=0, keepdims=True)
+    acc_ref[...] += acc
 
     @pl.when(k == n_k - 1)
     def _flush():
@@ -185,12 +217,14 @@ def plane_variant(
 ):
     """Cached jitted kernel variant specialized to one plane budget.
 
-    The plane count is a *specialization axis*: the kernel body unrolls
-    ``planes`` Horner steps, so a 4-plane variant issues exactly half the MXU
-    work of the 8-plane one — a dynamic-precision schedule that assigns a
-    layer 4 planes genuinely runs a smaller kernel, not a masked full-width
-    one.  Each distinct (planes, signed, block) tuple compiles once and is
-    reused across layers and calls; ``plane_variant.cache_info()`` exposes
+    The plane count is a *specialization axis*: it fixes the mask applied to
+    each x block before the one int8 MXU pass (none at 8 planes).  Every
+    variant does the same MXU work; what a smaller budget buys on the TPU is
+    a coarser operand, while the digit-serial latency and energy it saves on
+    the FPGA come from the models in ``core/``.  Each distinct (planes,
+    signed, scaled, block) tuple compiles once and is reused across layers
+    and calls, under the trace name ``mma_matmul[_scaled]_pallas_p<planes>``
+    (``u`` appended when unsigned); ``plane_variant.cache_info()`` exposes
     the variant table for tests and benchmarks.
     """
     impl = _mma_matmul_scaled_impl if scaled else _mma_matmul_impl
@@ -217,7 +251,8 @@ def mma_matmul_pallas(
     bk: int = BK,
     bn: int = BN,
 ) -> jax.Array:
-    """(M, K) int8 @ (K, N) int8 -> (M, N) int32, fused bit-plane Horner.
+    """(M, K) int8 @ (K, N) int8 -> (M, N) int32 over the ``planes`` most
+    significant digits of x, in one int8 pass per block.
 
     Shapes must be multiples of the block shape — ``ops.mma_matmul`` pads.
     Dispatches through the per-plane-count variant cache.

@@ -12,21 +12,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .mma_matmul import BK, BM, BN, N_BITS, mma_matmul_pallas
+from .mma_matmul import N_BITS, block_shape, mma_matmul_pallas, pad_to
 
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _pad_to(v: int, b: int) -> int:
-    return (v + b - 1) // b * b
-
-
 def _normalize_planes(x, planes, *, signed: bool):
     """Static plane budgets specialize the kernel (cached variant per count,
-    fewer unrolled MXU steps, validated 1..8); traced budgets fold into the
-    data via the exact bit-mask identity and run the full-width variant."""
+    each with its own operand mask, validated 1..8); traced budgets fold into
+    the data via the same exact bit-mask identity and run the full-width
+    variant."""
     from repro.core import bitplane  # lazy: core.mma imports this module lazily
 
     return bitplane.normalize_planes(x, planes, signed=signed)
@@ -52,14 +49,14 @@ def mma_matmul(
     for d in lead:
         m *= d
 
-    bm, bk, bn = block if block is not None else (BM, BK, BN)
+    bm, bk, bn = block_shape(m, k, n) if block is None else block
     # Shrink blocks for small problems (keeps interpret-mode tests fast);
     # int8 sublane tiling on TPU wants the second-minor dim in multiples of 32.
-    bm, bk, bn = min(bm, _pad_to(m, 32)), min(bk, _pad_to(k, 128)), min(bn, _pad_to(n, 128))
-    mp, kp, np_ = _pad_to(m, bm), _pad_to(k, bk), _pad_to(n, bn)
+    bm, bk, bn = min(bm, pad_to(m, 32)), min(bk, pad_to(k, 128)), min(bn, pad_to(n, 128))
+    mp, kp, np_ = pad_to(m, bm), pad_to(k, bk), pad_to(n, bn)
     with jax.named_scope("pack"):
-        # Zero-padding K is exact: padded w rows are 0, so both the dot and
-        # the signed colsum correction are unaffected (see kernel docstring).
+        # Zero-padding K is exact: padded w rows are 0, so neither the dot
+        # nor the unsigned path's colsum correction moves.
         x2 = jnp.pad(x.reshape(m, k), ((0, mp - m), (0, kp - k)))
         w2 = jnp.pad(w, ((0, kp - k), (0, np_ - n)))
     with jax.named_scope("mma"):
@@ -92,8 +89,8 @@ def mma_matmul_scaled(
     m = 1
     for d in lead:
         m *= d
-    bm, bk, bn = min(BM, _pad_to(m, 32)), min(BK, _pad_to(k, 128)), min(BN, _pad_to(n, 128))
-    mp, kp, np_ = _pad_to(m, bm), _pad_to(k, bk), _pad_to(n, bn)
+    bm, bk, bn = block_shape(m, k, n)
+    mp, kp, np_ = pad_to(m, bm), pad_to(k, bk), pad_to(n, bn)
     with jax.named_scope("pack"):
         x2 = jnp.pad(x.reshape(m, k), ((0, mp - m), (0, kp - k)))
         w2 = jnp.pad(w, ((0, kp - k), (0, np_ - n)))

@@ -142,7 +142,7 @@ def conv3x3(p, x, cfg: UNetConfig, *, planes: int | None = None):
     ``planes`` overrides the global ``cfg.planes`` for this layer — the hook
     the per-layer :class:`PlaneSchedule` drives.  Static per-layer budgets
     compile one specialized kernel variant per distinct count (shared across
-    layers), so a 4-plane layer runs half the MXU work of an 8-plane one.
+    layers); on the TPU each is one int8 MXU pass on the truncated operand.
     """
     if planes is None:
         planes = cfg.planes

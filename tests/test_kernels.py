@@ -124,3 +124,71 @@ def test_horner_equals_cascade_hlo_structure():
     merged = jax.jit(lambda a, b: ops.mma_matmul(a, b, interpret=True))
     text = merged.lower(x, w).as_text()
     assert "custom_call_target" in text or "pallas" in text.lower()
+
+
+# rows: the int8 extremes and the unsigned extremes (u = 255, 0) as int8 bits
+_X_ROWS = (-128, 127, -1, 0)
+# columns: +-127 and -128 weights, alone and mixed
+_W_COLS = (127, -127, -128, "alt")
+
+
+def _extreme_operands(k):
+    x = jnp.asarray(np.repeat(np.array(_X_ROWS)[:, None], k, axis=1), jnp.int8)
+    cols = [np.full(k, c) if c != "alt" else np.resize([127, -128], k) for c in _W_COLS]
+    return x, jnp.asarray(np.stack(cols, axis=1), jnp.int8)
+
+
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "scaled"])
+@pytest.mark.parametrize("signed", [True, False], ids=["signed", "unsigned"])
+@pytest.mark.parametrize("planes", range(1, 9))
+@pytest.mark.parametrize("k", [9, 13824])  # a 3x3 conv on one channel; kits conv10
+def test_kernel_exact_at_operand_extremes(k, planes, signed, scaled):
+    """Saturated operands at every plane budget, bit for bit against the
+    oracle: the one int8 pass keeps every product and sum exact at a
+    contraction as long as the largest U-Net conv's."""
+    x, w = _extreme_operands(k)
+    # the oracle reads unsigned activations as the 0..255 values of the bits
+    xr = x if signed else x.astype(jnp.int32) & 0xFF
+    want = ref.mma_matmul_ref(xr, w, planes=planes, signed=signed)
+    if scaled:
+        # power-of-two scales: the epilogue's multiplies are exact
+        xs = jnp.float32(2.0**-10)
+        ws = jnp.asarray([2.0**-3, 2.0**-7, 1.0, 2.0**-1], jnp.float32)
+        got = ops.mma_matmul_scaled(x, w, xs, ws, planes=planes, signed=signed,
+                                    interpret=True)
+        want = want.astype(jnp.float32) * xs * ws
+    else:
+        got = ops.mma_matmul(x, w, planes=planes, signed=signed, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _eqns(jaxpr, name):
+    """Every ``name`` equation of a jaxpr, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, name)
+
+
+@pytest.mark.parametrize("scaled", [False, True], ids=["plain", "scaled"])
+@pytest.mark.parametrize("planes", range(1, 9))
+def test_kernel_one_int8_pass_per_block(planes, scaled):
+    """The kernel body makes one int8 x int8 -> int32 MXU pass per grid step
+    at every plane budget: no per-plane passes, no widening to bf16."""
+    from repro.kernels import mma_matmul as mk
+
+    x, w = _rand_i8((64, 256)), _rand_i8((256, 128))
+    if scaled:
+        fn = mk.plane_variant(planes, scaled=True, interpret=True, bm=32, bk=128)
+        args = (x, w, jnp.float32(1.0), jnp.ones((128,), jnp.float32))
+    else:
+        fn = mk.plane_variant(planes, interpret=True, bm=32, bk=128)
+        args = (x, w)
+    calls = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr, "pallas_call"))
+    assert len(calls) == 1
+    dots = list(_eqns(calls[0].params["jaxpr"], "dot_general"))
+    assert len(dots) == 1
+    (dot,) = dots
+    assert [v.aval.dtype for v in dot.invars] == [jnp.int8, jnp.int8]
+    assert dot.params["preferred_element_type"] == jnp.int32

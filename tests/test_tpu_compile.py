@@ -54,14 +54,22 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
-@pytest.mark.parametrize("planes", [8, 4])
-@pytest.mark.parametrize("layer", range(len(LAYERS)))
+# unet64_kits512 (base 64, depth 4, two convs per stage) at its 352x352
+# serving window: the largest-M convs (00, 01, 16) and the largest-K one (10)
+KITS_LAYERS = unet.UNetConfig(
+    in_ch=1, base=64, depth=4, convs_per_stage=2, hw=(352, 352)).conv_layers()
+KITS = {f"kits352-conv{i:02d}": KITS_LAYERS[i] for i in (0, 1, 10, 16)}
+
+
+@pytest.mark.parametrize("planes", [8, 5, 4, 1])
+@pytest.mark.parametrize("layer", [*range(len(LAYERS)), *KITS])
 @pytest.mark.parametrize("scaled", [False, True], ids=["plain", "scaled"])
 def test_mma_kernel_compiles_at_unet_conv_shape(one_chip, layer, planes, scaled):
     """Each 3x3 conv of the served tile forward as its im2col matmul:
     (batch*h*w, 9*cin) @ (9*cin, cout), through the plain int32 kernel and
-    the fused-dequant kernel."""
-    spec = LAYERS[layer]
+    the fused-dequant kernel, at the calibrated geometry and at the kits
+    window, whose shapes take the block rule's largest VMEM footprint."""
+    spec = KITS[layer] if layer in KITS else LAYERS[layer]
     m, k, n = BATCH * spec.h * spec.w, 9 * spec.cin, spec.cout
     x = _sds((m, k), jnp.int8, one_chip)
     w = _sds((k, n), jnp.int8, one_chip)
