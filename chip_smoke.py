@@ -68,8 +68,8 @@ def unet_phase(*, image_hw: int = 240, n_images: int = 4, tile: int = 80,
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core import quant
-    from repro.kernels import ops, ref
+    from repro.core import mma, quant
+    from repro.kernels import ref
     from repro.models import unet
     from repro.segserve import SegEngine, tiling
     from repro.segserve.synth import phantom_image
@@ -92,7 +92,7 @@ def unet_phase(*, image_hw: int = 240, n_images: int = 4, tile: int = 80,
     @jax.jit
     def conv_diffs(x, wq):
         return jnp.stack([
-            jnp.max(jnp.abs(ops.mma_conv2d(x, wq, planes=p)
+            jnp.max(jnp.abs(mma.mma_conv2d(x, wq, planes=p, impl="pallas")
                             - ref.mma_conv2d_ref(x, wq, planes=p)))
             for p in budgets])
 

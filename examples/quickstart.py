@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python examples/quickstart.py
 
-1. bit-plane merged multiply-add vs exact int8 matmul (bit-exact),
+1. the MMA datapath, XLA and the Pallas kernel, vs the int8 oracle (bit-exact),
 2. MSDF early termination (progressive precision),
 3. a quantized linear layer inside a tiny LM forward.
 """
@@ -10,8 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import bitplane, early_term, mma, quant
-from repro.kernels import ops, ref
+from repro.core import early_term, mma
+from repro.kernels import ref
 from repro.launch.compile_cache import enable_compile_cache
 
 
@@ -22,9 +22,9 @@ def main():
     w = jnp.asarray(rng.integers(-128, 128, (256, 32)), jnp.int8)
 
     exact = ref.mma_matmul_ref(x, w)
-    merged = mma.mma_dot(x, w, impl="xla")
-    pallas = ops.mma_matmul(x, w)  # interpret mode only off the TPU
-    print("merged == exact:", bool(jnp.array_equal(merged, exact)))
+    xla = mma.mma_dot(x, w, impl="xla")
+    pallas = mma.mma_dot(x, w, impl="pallas")  # interpret mode only off the TPU
+    print("xla == exact:", bool(jnp.array_equal(xla, exact)))
     print("pallas == exact:", bool(jnp.array_equal(pallas, exact)))
 
     print("\nMSDF progressive precision (planes -> max relative error):")

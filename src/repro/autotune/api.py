@@ -203,7 +203,7 @@ def tune_unet(
     geometry = dict(
         hw=cfg.hw, in_ch=cfg.in_ch, base=cfg.base, depth=cfg.depth,
         convs_per_stage=cfg.convs_per_stage, n_classes=cfg.n_classes,
-        impl=cfg.impl, pad_mode=cfg.pad_mode,
+        impl=cfg.impl,
     )
 
     def build(planes_now, class_planes_now, certificate) -> TunedPlan:

@@ -29,7 +29,7 @@ class QuantConfig:
     # keep the global ``planes``.  Build with
     # core.PlaneSchedule.from_weights / serve.engine.lm_schedule_from_params.
     plane_schedule: tuple[int, ...] | None = None
-    impl: str = "xla"  # 'xla' | 'pallas' | 'cascade' | 'int8'
+    impl: str = "xla"  # MMA datapath: 'xla' | 'pallas' (see core.mma)
     # Serving extensions (beyond-paper, §Perf iteration 3): store weights as
     # int8 (+per-channel scale) instead of quantizing bf16 on the fly, and
     # keep the KV cache in int8 with a calibrated static scale.

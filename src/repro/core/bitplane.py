@@ -71,8 +71,8 @@ def truncate_to_planes(
     bits of ``u``.  Because the mask is computed with jnp shifts, ``planes``
     may be a *traced* scalar — this is what lets a per-layer
     :class:`~repro.core.plane_schedule.PlaneSchedule` ride a ``lax.scan``
-    over stacked layer params while every datapath (including the
-    bit-parallel int8 baseline) sees ordinary int8 operands.
+    over stacked layer params while both MMA datapaths (``core.mma``) see
+    ordinary int8 operands.
     """
     u = x.astype(jnp.int32)
     if signed:
@@ -91,11 +91,11 @@ def normalize_planes(
     """Resolve a per-call plane budget to (operand, static planes).
 
     Static Python ints are validated (1..N_BITS) and passed through — the
-    kernel paths specialize on them and genuinely skip plane iterations.
-    Traced scalars — one entry of a PlaneSchedule riding a ``lax.scan`` —
-    are folded into the *data* via :func:`truncate_to_planes`, after which
-    every datapath runs its full-precision path on the pre-truncated
-    operand: identical numerics, one fused matmul.
+    kernel specializes on them (one cached variant per count).  Traced
+    scalars — one entry of a PlaneSchedule riding a ``lax.scan`` — are
+    folded into the *data* via :func:`truncate_to_planes`, after which the
+    datapath runs its full-precision path on the pre-truncated operand:
+    identical numerics, one fused matmul.
     """
     if isinstance(planes, int):
         if not (1 <= planes <= N_BITS):
@@ -115,8 +115,9 @@ def bitplane_matmul(
     """Exact (planes=8) or progressively-truncated (planes<8) int matmul.
 
     Computes ``x @ w`` in int32 via MSB-first bit-plane accumulation — the
-    pure-XLA reference of the MMA datapath (the Pallas kernel in
-    ``repro.kernels.mma_matmul`` fuses the same recurrence into VMEM).
+    paper's digit-serial recurrence, one product per plane.  The MMA
+    datapaths (``core.mma``) compute the same integers (without
+    ``correction``) as one product on the plane-masked operand.
 
     With ``planes = b < 8`` only the ``b`` most significant planes are
     consumed — the paper's early termination.  The partial Horner sum is

@@ -107,10 +107,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, quant: str = "none"
         from repro.configs.base import QuantConfig
 
         # serving deploy mode: pre-quantized int8 weights + int8 KV cache,
-        # int8 MXU dot as the compute model (the Pallas bit-plane kernel is
-        # the TPU implementation; its MXU cost equals the int8 dot here).
+        # the int8 MXU dot as the compute model (the Pallas kernel is the
+        # TPU implementation; it makes the same one int8 pass per block).
         cfg = cfg.replace(quant=QuantConfig(
-            mode=quant, impl="int8", weights_int8=True, kv_int8=True))
+            mode=quant, impl="xla", weights_int8=True, kv_int8=True))
     if overrides:
         cfg = apply_overrides(cfg, overrides)
     mesh = make_production_mesh(multi_pod=multi_pod)

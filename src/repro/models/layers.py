@@ -56,8 +56,6 @@ def linear(p: dict, x: jax.Array, quant=None) -> jax.Array:
     mode) carry pre-quantized int8 weights: half the HBM bytes of bf16 and
     no requantization per step.
     """
-    from repro.core import quant as quant_lib
-
     # Per-row activation scales for batched inputs: a tensor-wide amax lets
     # one batch row's magnitudes shift every other row's quantization grid,
     # breaking the slot-isolation invariant the serving engines document
@@ -65,23 +63,12 @@ def linear(p: dict, x: jax.Array, quant=None) -> jax.Array:
     # per-tensor grid).
     batch_axis = 0 if x.ndim >= 3 else None
     if "w_q" in p:
-        planes = quant.planes if quant is not None else 8
-        impl = quant.impl if quant is not None else "xla"
-        xq = quant_lib.quantize_acts(
-            x.astype(jnp.float32),
-            batch_axis=None if impl == "pallas" else batch_axis,
-        )
-        w_scale = jnp.squeeze(p["w_scale"], axis=-2)
-        if impl == "pallas":
-            from repro.kernels import ops as kops
-
-            out = kops.mma_matmul_scaled(
-                xq.values, p["w_q"], xq.scale, w_scale, planes=planes
-            ).astype(x.dtype)
-        else:
-            out_i32 = mma.mma_dot(xq.values, p["w_q"], planes=planes, impl=impl)
-            out = (out_i32.astype(jnp.float32)
-                   * (xq.scale * w_scale)).astype(x.dtype)
+        out = mma.mma_linear_int8(
+            x.astype(jnp.float32), p["w_q"], jnp.squeeze(p["w_scale"], axis=-2),
+            planes=quant.planes if quant is not None else 8,
+            impl=quant.impl if quant is not None else "xla",
+            batch_axis=batch_axis,
+        ).astype(x.dtype)
     else:
         w = p["w"]
         if quant is not None and quant.mode == "mma_int8":

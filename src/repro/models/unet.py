@@ -2,8 +2,8 @@
 
 Faithful to the paper's deployment: the network is trained in float (or
 QAT), quantized FBGEMM-style to int8, and its 3x3 convolutions execute on
-the MSDF merged multiply-add datapath (``core.mma`` / ``kernels.mma_conv2d``
-— the KPB maps the k*k taps into the contraction dim).  2x2 pool/upsample
+the MSDF merged multiply-add datapath (``core.mma.mma_conv2d`` — the KPB
+maps the k*k taps into the contraction dim).  2x2 pool/upsample
 and the final 1x1 conv run off the accelerator, as in the paper (Sec. 3.1).
 
 The default geometry is the Table-1-calibrated config
@@ -17,6 +17,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.core import mma, quant
 from repro.core.cycle_model import CALIBRATED_UNET, ConvLayerSpec, unet_conv_layers
 from repro.core.plane_schedule import PlaneSchedule
 
@@ -34,11 +35,7 @@ class UNetConfig:
     # Per-3x3-conv plane budgets, in forward order (enc, bottleneck, dec) —
     # same order as ``conv_layers()``.  None -> uniform ``planes``.
     plane_schedule: tuple[int, ...] | None = None
-    impl: str = "xla"  # mma impl: xla | pallas | cascade | int8
-    # Border fill of every 3x3 conv: 'zero' (the SAME convention) or
-    # 'edge' / 'reflect' — the external padding control halo-free image
-    # tiles use (see kernels.ops.mma_conv2d and repro.segserve).
-    pad_mode: str = "zero"
+    impl: str = "xla"  # MMA datapath: 'xla' | 'pallas' (see core.mma)
     family: str = "unet"
 
     def conv_layers(self) -> list[ConvLayerSpec]:
@@ -147,28 +144,16 @@ def conv3x3(p, x, cfg: UNetConfig, *, planes: int | None = None):
     if planes is None:
         planes = cfg.planes
     if cfg.quant_mode == "mma_int8":
-        from repro.core import quant
-        from repro.kernels import ops
-
         with jax.named_scope("quant"):
             xq = quant.quantize_acts(x)
             wq = quant.quantize_weights(p["w"], channel_axis=-1)
-        out = ops.mma_conv2d(
-            xq.values, wq.values, planes=planes, impl=cfg.impl,
-            pad_mode=cfg.pad_mode,
-        )
+        out = mma.mma_conv2d(xq.values, wq.values, planes=planes, impl=cfg.impl)
         with jax.named_scope("rescale"):
             out = out.astype(jnp.float32) * quant.quantized_matmul_scale(xq.scale, wq.scale)
             return out + p["b"]
-    elif cfg.pad_mode == "zero":
-        out = jax.lax.conv_general_dilated(
-            x, p["w"], (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
-        )
-    else:
-        xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)), mode=cfg.pad_mode)
-        out = jax.lax.conv_general_dilated(
-            xp, p["w"], (1, 1), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC")
-        )
+    out = jax.lax.conv_general_dilated(
+        x, p["w"], (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
+    )
     return out + p["b"]
 
 
@@ -271,7 +256,6 @@ def forward_with_error_bound(params, x, cfg: UNetConfig):
     worst-case sound — unlike the first-order per-layer sum
     (``PlaneSchedule.rel_err_bound``), which ignores inter-layer gain.
     """
-    from repro.core import quant
     from repro.core.bitplane import N_BITS
 
     sched = cfg.schedule()
@@ -359,8 +343,6 @@ def schedule_from_params(
     """Build the per-layer precision policy from this net's actual weights:
     quantize each 3x3 conv FBGEMM-style and pick the fewest planes whose
     analytic worst-case relative error meets ``target_rel_err``."""
-    from repro.core import quant
-
     wq = [
         quant.quantize_weights(w, channel_axis=-1).values.reshape(-1, w.shape[-1])
         for w in conv_weights_in_order(params)

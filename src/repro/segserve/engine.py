@@ -221,7 +221,8 @@ class SegEngine:
       params: U-Net params for ``cfg``.
       tile: core stride (multiple of ``2**depth``).
       halo: exact by default (:func:`~repro.segserve.tiling.halo_for`);
-        0 + ``cfg.pad_mode='edge'`` is the cheap seam-tolerant mode.
+        smaller halos (down to 0) are legal and approximate: each tile's
+        convs zero-pad at the cut, so seams carry error.
       batch: fixed tile micro-batch size (short groups are zero-padded).
       max_active: slot-table capacity — concurrent stitching canvases.
       adaptive: refine the layer schedule per budget class (quantized

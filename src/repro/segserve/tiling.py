@@ -143,8 +143,9 @@ def plan_tiles(
     needs it; the pad strip rides the bottom/right tiles and is cropped off
     after stitching).  Cores of ``tile x tile`` (smaller at the far edges)
     stride the canvas; ``halo`` defaults to the exact :func:`halo_for`
-    width and may be overridden — smaller halos (down to 0, typically with
-    ``pad_mode='edge'``) buy cycles at the price of seam error.
+    width and may be overridden — smaller halos (down to 0) buy cycles at
+    the price of seam error: the cut sees zero padding where the image has
+    content.
     """
     if h < 1 or w < 1:
         raise ValueError(f"image dims {h}x{w} must be positive")

@@ -8,6 +8,7 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core import bitplane, early_term, quant
 from repro.core import cycle_model as cm
+from repro.kernels import ref
 
 
 # --------------------------------------------------------------- bit planes
@@ -33,6 +34,23 @@ def test_bitplane_matmul_exact():
     np.testing.assert_array_equal(
         np.asarray(bitplane.bitplane_matmul_cascade(x, w)), np.asarray(want)
     )
+
+
+@pytest.mark.parametrize("shape", [(16, 128, 128), (37, 100, 65), (1, 7, 3), (9, 300, 130)],
+                         ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("planes", [8, 6, 3, 1])
+@pytest.mark.parametrize("fn", [bitplane.bitplane_matmul, bitplane.bitplane_matmul_cascade],
+                         ids=lambda f: f.__name__)
+def test_recurrences_vs_oracle(fn, planes, shape):
+    """The paper's per-plane Horner recurrence and its un-merged cascade
+    consume the ``planes`` MSB digits exactly as the masked-matmul oracle
+    does, at K and N on and off the 128 tiles."""
+    m, k, n = shape
+    rng = np.random.default_rng(m * k + n + planes)
+    x = jnp.asarray(rng.integers(-128, 128, (m, k)), jnp.int8)
+    w = jnp.asarray(rng.integers(-128, 128, (k, n)), jnp.int8)
+    want = ref.mma_matmul_ref(x, w, planes=planes)
+    np.testing.assert_array_equal(np.asarray(fn(x, w, planes=planes)), np.asarray(want))
 
 
 @given(st.integers(1, 8))

@@ -304,28 +304,6 @@ def test_engine_adaptive_reduces_cycles_at_same_error():
     assert err_a <= err_u + 0.05
 
 
-def test_engine_zero_halo_edge_padding_mode():
-    """The cheap mode: halo=0 with edge-replicate conv padding runs and,
-    on smooth content (the case it exists for), leaves far smaller *seam*
-    error than a hard zero cut.  Real image borders are excluded — there
-    the zero-SAME reference is the thing edge padding deliberately trades
-    away — so the comparison isolates the artificial tile boundaries."""
-    cfg, params = _net(2)
-    yy, xx = np.mgrid[0:48, 0:48].astype(np.float32) / 48.0
-    image = np.stack([1.0 + yy, 1.0 + xx, 1.5 + yy * xx], axis=-1)
-    want = _whole_ref(params, image, cfg)
-    res_edge = SegEngine(
-        dataclasses.replace(cfg, pad_mode="edge"), params, tile=8, halo=0
-    ).run([image])[0]
-    res_zero = SegEngine(cfg, params, tile=8, halo=0).run([image])[0]
-    b = tiling.halo_for(cfg.depth, cfg.convs_per_stage)  # interior crop
-    interior = (slice(b, -b), slice(b, -b))
-    err_edge = float(np.max(np.abs((res_edge.logits - want)[interior])))
-    err_zero = float(np.max(np.abs((res_zero.logits - want)[interior])))
-    assert err_edge > 0  # approximate by design
-    assert err_edge < err_zero
-
-
 def test_engine_rejects_bad_image():
     cfg, params = _net(1)
     eng = SegEngine(cfg, params, tile=8)
@@ -363,28 +341,6 @@ def test_unet_forward_rejects_misaligned_input():
     cfg, params = _net(2)
     with pytest.raises(ValueError, match="divisible"):
         unet.forward(params, jnp.zeros((1, 18, 16, 3)), cfg)
-
-
-@pytest.mark.parametrize("mode", ["edge", "reflect"])
-def test_conv_pad_modes_match_manual_pad(mode):
-    from repro.kernels import ops
-
-    rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.integers(-128, 128, (2, 6, 7, 3)), jnp.int8)
-    w = jnp.asarray(rng.integers(-128, 128, (3, 3, 3, 4)), jnp.int8)
-    got = ops.mma_conv2d(x, w, pad=1, pad_mode=mode, impl="xla")
-    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)), mode=mode)
-    want = ops.mma_conv2d(xp, w, pad=0, impl="xla")
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_conv_pad_mode_validation():
-    from repro.kernels import ops
-
-    x = jnp.zeros((1, 4, 4, 2), jnp.int8)
-    w = jnp.zeros((3, 3, 2, 2), jnp.int8)
-    with pytest.raises(ValueError):
-        ops.mma_conv2d(x, w, pad_mode="wrap", impl="xla")
 
 
 def test_rectangular_conv_layers():
